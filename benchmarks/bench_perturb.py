@@ -53,10 +53,10 @@ def run():
         rec = {"backend": backend, "status": "ok"}
         try:
             if backend == "pallas":
-                # force the COMPILED kernel: off-TPU get_backend("pallas")
-                # silently falls back to interpret mode, which would just
-                # duplicate the pallas-interpret row instead of reporting
-                # "unavailable" honestly
+                # force the COMPILED kernel: on the CPU get_backend("pallas")
+                # interprets, which would just duplicate the
+                # pallas-interpret row instead of reporting "unavailable"
+                # honestly
                 from repro.perturb import PallasBackend
                 be = PallasBackend(interpret=False)
             else:

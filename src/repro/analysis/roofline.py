@@ -121,8 +121,6 @@ class Roofline:
 def from_compiled(arch: str, cell: str, mesh_name: str, chips: int,
                   compiled, model_fl: dict) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):       # older API returned [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     try:

@@ -30,6 +30,7 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.exec import plan as plan_mod
 from repro.exec.plan import ExecPlan, check_replay_plan
@@ -286,16 +287,72 @@ class StepProgram:
                        donate_argnums=(0,) if donate else ())
 
     # -- seed-parallel lowering (n_groups > 1; n == 1 delegates to local) --- #
+    def _group_eval(self, loss_fn, params, batch, state, skey, est_state,
+                    tf_state, seed_index: int, phase):
+        """One group of a seed-parallel step: its estimate at ``params`` on
+        its batch slice, and its transform output.  ``phase`` is None when
+        the selection schedule has a single phase."""
+        est, tf = self.opt.estimator, self.opt.transform
+        if phase is None:
+            e = est.estimate(loss_fn, params, batch, skey, est_state)
+        else:
+            e = est.estimate(loss_fn, params, batch, skey, est_state,
+                             phase=phase)
+        ctx = TransformCtx(step=state.step, base_key=state.base_key,
+                           key=skey, seed_index=seed_index,
+                           n_seeds=self.plan.n_groups, eps=est.eps,
+                           dist=est.dist, restore=e.restore,
+                           backend=self.opt.backend)
+        u, tf_state = tf.update(Updates(g=e.projected_grad), tf_state, ctx)
+        if u.final_params is not None:
+            # unreachable behind the __init__ applier guard; loud (not
+            # silently dropped) if that guard is ever relaxed
+            raise ValueError(
+                "a transform materialized final_params under a multi-group "
+                "plan; group updates are rank-1 applications and cannot "
+                "honor it")
+        return e, u, tf_state
+
+    @staticmethod
+    def _group_result(state: ZOState, est_state, tf_state, gs, losses, lr,
+                      aux: dict):
+        """The step's new state and metrics from the stacked per-group
+        projected grads and losses."""
+        g_mean = jnp.mean(gs)
+        new_state = ZOState(state.step + 1, state.base_key, est_state,
+                            tf_state, g_mean)
+        metrics = {"loss": jnp.mean(losses), "projected_grad": g_mean,
+                   "lr": lr if lr is not None else jnp.float32(1.0), **aux,
+                   "projected_grads": gs.reshape(-1)}
+        return new_state, metrics
+
+    def _group_mesh_axis(self):
+        """The mesh axis (or axes) that holds one seed group per device, or
+        None when the plan's mesh is not purely that group mesh (its batch
+        axes hold n_groups devices and every other axis one)."""
+        from repro.distributed.sharding import axis_size, batch_axes
+        mesh = self.plan.mesh
+        if mesh is None:
+            return None
+        ba = batch_axes(mesh)
+        if not ba or axis_size(mesh, ba) != self.plan.n_groups:
+            return None
+        if any(size != 1 for a, size in mesh.shape.items() if a not in ba):
+            return None
+        return ba if len(ba) > 1 else ba[0]
+
     def _seed_parallel_step_fn(self, loss_fn) -> Callable:
         opt = self.opt
-        est, tf = opt.estimator, opt.transform
+        est = opt.estimator
         n = self.plan.n_groups
         backend = opt.backend
         batch_seeds = opt.batch_seeds
         sel = opt.selection
         n_phases = 1 if sel is None else int(sel.n_phases)
+        group_axis = self._group_mesh_axis()
 
-        def body(params: PyTree, state: ZOState, batch, phase: int):
+        def sequential(params: PyTree, state: ZOState, batch, phase):
+            """The groups in turn; under a mesh, GSPMD lays them out."""
             skey0 = step_key(state.base_key, state.step)
             p = params
             est_state, tf_state = state.est_state, state.tf_state
@@ -304,27 +361,10 @@ class StepProgram:
             lr_metric = None
             decay0 = 0.0
             for g in range(n):
-                skey = group_key(skey0, g, n)
-                if n_phases > 1:
-                    e = est.estimate(loss_fn, p, slice_group(batch, g, n),
-                                     skey, est_state, phase=phase)
-                else:
-                    e = est.estimate(loss_fn, p, slice_group(batch, g, n),
-                                     skey, est_state)
+                e, u, tf_state = self._group_eval(
+                    loss_fn, p, slice_group(batch, g, n), state,
+                    group_key(skey0, g, n), est_state, tf_state, g, phase)
                 est_state = e.est_state
-                ctx = TransformCtx(step=state.step, base_key=state.base_key,
-                                   key=skey, seed_index=g, n_seeds=n,
-                                   eps=est.eps, dist=est.dist,
-                                   restore=e.restore, backend=backend)
-                u, tf_state = tf.update(Updates(g=e.projected_grad), tf_state,
-                                        ctx)
-                if u.final_params is not None:
-                    # unreachable behind the __init__ applier guard; loud
-                    # (not silently dropped) if that guard is ever relaxed
-                    raise ValueError(
-                        "a transform materialized final_params under a "
-                        "multi-group plan; group updates are rank-1 "
-                        "applications and cannot honor it")
                 # evaluations stay at the step's center; directions are
                 # averaged afterwards through the shared write path
                 p = e.restore()
@@ -339,15 +379,50 @@ class StepProgram:
             p = apply_group_updates(p, skey0, coeffs, decay0, n,
                                     batch_seeds, est.dist, backend,
                                     selection=sel, phase=phase)
-            g_mean = jnp.mean(jnp.stack(gs))
-            if lr_metric is None:
-                lr_metric = jnp.float32(1.0)
-            new_state = ZOState(state.step + 1, state.base_key,
-                                est_state, tf_state, g_mean)
-            metrics = {"loss": jnp.mean(jnp.stack(losses)),
-                       "projected_grad": g_mean, "lr": lr_metric, **aux,
-                       "projected_grads": jnp.stack(gs).reshape(-1)}
+            new_state, metrics = self._group_result(
+                state, est_state, tf_state, jnp.stack(gs), jnp.stack(losses),
+                lr_metric, aux)
             return p, new_state, metrics
+
+        def per_device(params: PyTree, state: ZOState, batch, phase):
+            """Device g of the group mesh: group g on its batch slice, the
+            per-group scalars all-gathered (the step's only cross-device
+            traffic), then all n rank-1 updates, so θ stays replicated."""
+            skey0 = step_key(state.base_key, state.step)
+            # seed_index 0: the decay slot is group 0's (add_weight_decay
+            # keys it on the index, which is traced here); every other field
+            # is this device's group
+            e, u, tf_state = self._group_eval(
+                loss_fn, params, batch, state,
+                group_key(skey0, jax.lax.axis_index(group_axis), n),
+                state.est_state, state.tf_state, 0, phase)
+            gather = functools.partial(jax.lax.all_gather,
+                                       axis_name=group_axis)
+            coeffs = gather(u.coeff if u.coeff is not None else u.g)
+            aux = {k: gather(v)[n - 1] for k, v in (e.aux or {}).items()}
+            p = apply_group_updates(e.restore(), skey0,
+                                    [coeffs[i] for i in range(n)], u.decay,
+                                    n, batch_seeds, est.dist, backend,
+                                    selection=sel, phase=phase)
+            new_state, metrics = self._group_result(
+                state, e.est_state, tf_state, gather(u.g), gather(e.loss),
+                u.lr, aux)
+            return p, new_state, metrics
+
+        def body(params: PyTree, state: ZOState, batch, phase: int):
+            phase = phase if n_phases > 1 else None
+            # a stateful estimator or chain threads its state group to
+            # group, which independent devices cannot reproduce: it keeps
+            # the sequential body, as does any mesh with other axes
+            if group_axis is None or jax.tree_util.tree_leaves(
+                    (state.est_state, state.tf_state)):
+                return sequential(params, state, batch, phase)
+            bspec = jax.tree_util.tree_map(
+                lambda x: P(group_axis) if jnp.ndim(x) else P(), batch)
+            return jax.shard_map(
+                functools.partial(per_device, phase=phase),
+                mesh=self.plan.mesh, in_specs=(P(), P(), bspec),
+                out_specs=P(), check_vma=False)(params, state, batch)
 
         if n_phases == 1:
             def step(params: PyTree, state: ZOState, batch):
@@ -378,7 +453,7 @@ class StepProgram:
         if mesh is None:
             raise ValueError("this plan carries no mesh; construct it as "
                              "exec.seed_parallel(n, mesh=...)")
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding
 
         from repro.distributed.sharding import batch_axes, param_shardings
         pshard = param_shardings(params_like, mesh)

@@ -8,20 +8,16 @@ assembled into a dense ``(T, D)`` run.  This kernel is that gather:
 
     out[l, i*block : (i+1)*block, :] = x[l, table[i]*block : …, :]
 
-following the ``rows.py`` tile-skip idiom: the *indices* ride in as a small
-``(n, 1)`` int32 input blocked ``(1, 1)`` per grid step, the payload rows are
-copied block-at-a-time, and the arithmetic is a pure copy — so the kernel is
-bitwise-equal to the XLA gather by construction (``paged_gather_ref``,
-test-enforced).  Unlike ``rows.py`` the table is *runtime* data (block tables
-change every admission), so the source ref stays whole-array and the row
-window is a dynamic slice on the token axis.
+The block table is scalar-prefetched (``PrefetchScalarGridSpec``): it sits in
+SMEM before the grid runs, and the pool's BlockSpec index map reads it, so
+each grid step DMAs exactly one ``(1, block, D)`` pool block HBM→VMEM and the
+pool is never resident as a whole.  The arithmetic is a pure copy, so the
+kernel is bitwise-equal to the XLA gather by construction
+(``paged_gather_ref``, test-enforced).
 
-Interpret-mode fallback mirrors the other kernels: off-TPU the call runs
-under ``interpret=True`` (CPU CI exercises the real kernel semantics).  On a
-real TPU the whole-pool VMEM residency bounds pool size (~16 MB/core); the
-compiled-Mosaic characterization harness owns that path — a scalar-prefetch
-(``PrefetchScalarGridSpec``) variant that streams blocks HBM→VMEM is the
-recorded follow-up there.
+``interpret=True`` runs the same grid as jnp on the CPU (tests); callers pick
+it with ``repro.kernels.interpret_default()``, which refuses a platform that
+is neither CPU nor TPU.
 """
 from __future__ import annotations
 
@@ -30,13 +26,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _gather_kernel(tab_ref, x_ref, o_ref, *, block: int, cols: int):
-    layer = pl.program_id(0)
-    t = tab_ref[0, 0]
-    o_ref[0] = jax.lax.dynamic_slice(
-        x_ref[layer], (t * block, 0), (block, cols))
+def _gather_kernel(tab_ref, x_ref, o_ref):
+    del tab_ref                  # consumed by the index map
+    o_ref[...] = x_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -50,18 +45,19 @@ def paged_gather(x: jnp.ndarray, table: jnp.ndarray, block: int,
     """
     L, NT, D = x.shape
     n = int(table.shape[0])
-    tab = table.reshape(n, 1).astype(jnp.int32)
-    return pl.pallas_call(
-        functools.partial(_gather_kernel, block=block, cols=D),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(L, n),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda l, i: (i, 0)),
-            pl.BlockSpec((L, NT, D), lambda l, i: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block, D), lambda l, i: (l, i, 0)),
+        in_specs=[pl.BlockSpec((1, block, D),
+                               lambda l, i, tab: (l, tab[i], 0))],
+        out_specs=pl.BlockSpec((1, block, D), lambda l, i, tab: (l, i, 0)),
+    )
+    return pl.pallas_call(
+        _gather_kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((L, n * block, D), x.dtype),
         interpret=interpret,
-    )(tab, x)
+    )(table.astype(jnp.int32), x)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))

@@ -22,7 +22,9 @@ Grid: 1-D over row-blocks of the (padded) 2-D view; BlockSpec keeps one
 (block_rows × 128·lane_cols) tile of x and y in VMEM (~256 KB at f32).
 ``zo_affine_2d_batched`` adds an inner batch grid axis: B z-streams are
 generated against each resident x tile (the ``perturb_many`` entry point for
-batched-seed estimators).
+batched-seed estimators).  Seeds and affine coefficients are whole 1-D arrays
+in SMEM (``SMEM_SPEC``), indexed by grid position inside the kernel: Mosaic
+refuses a ``(1, 1)`` VMEM block over a ``(B, 1)`` array.
 """
 from __future__ import annotations
 
@@ -31,9 +33,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256
 BLOCK_COLS = 512          # multiple of 128 lanes
+
+# whole-array scalar operand (per-stream seeds, coefficients, tile ids)
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _murmur_mix(h: jnp.ndarray) -> jnp.ndarray:
@@ -72,8 +78,9 @@ def counter_uniform(idx: jnp.ndarray, seed: jnp.ndarray, salt: int,
     h = h ^ (seed * jnp.uint32(0x7FEB352D))
     h = h + jnp.uint32(salt) * jnp.uint32(0x846CA68B)
     h = _murmur_mix(h)
-    # 24 mantissa-ish bits -> (0,1); +1 avoids exactly 0 for the log
-    u = _pin((h >> jnp.uint32(8)).astype(jnp.float32), pin)
+    # 24 mantissa-ish bits -> (0,1); +1 avoids exactly 0 for the log.  The
+    # value is < 2^24, so the int32 hop is exact; Mosaic has no u32 -> f32.
+    u = _pin((h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32), pin)
     return u * (1.0 / 16777216.0) + (0.5 / 16777216.0)
 
 
@@ -216,8 +223,8 @@ def _tile_affine(x: jnp.ndarray, row_block: jnp.ndarray, cols: int,
 def _zo_affine_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *, cols: int,
                       interpret: bool, dist: str):
     i = pl.program_id(0)
-    seed = seed_ref[0, 0].astype(jnp.uint32)
-    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0, 0], b_ref[0, 0],
+    seed = seed_ref[0].astype(jnp.uint32)
+    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0], b_ref[0],
                      interpret, dist)
     o_ref[...] = y.astype(o_ref.dtype)
 
@@ -236,16 +243,14 @@ def zo_affine_2d(x: jnp.ndarray, seed: jnp.ndarray, a: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
-    )(x, seed.reshape(1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(1, 1),
-      jnp.asarray(b, jnp.float32).reshape(1, 1))
+    )(x, seed.reshape(1).astype(jnp.int32),
+      jnp.asarray(a, jnp.float32).reshape(1),
+      jnp.asarray(b, jnp.float32).reshape(1))
 
 
 def _zo_affine_batched_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
@@ -258,8 +263,8 @@ def _zo_affine_batched_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # single-seed kernel runs, which is what makes the batched output
     # bitwise-equal to stacked single-seed calls.
     i = pl.program_id(0)
-    seed = seed_ref[0, 0].astype(jnp.uint32)
-    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0, 0], b_ref[0, 0],
+    seed = seed_ref[pl.program_id(1)].astype(jnp.uint32)
+    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0], b_ref[0],
                      interpret, dist)
     o_ref[0, ...] = y.astype(o_ref.dtype)
 
@@ -286,13 +291,11 @@ def zo_affine_2d_batched(x: jnp.ndarray, seeds: jnp.ndarray, a: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((1, BLOCK_ROWS, cols), lambda i, j: (j, i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, rows, cols), x.dtype),
         interpret=interpret,
-    )(x, seeds.reshape(-1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(1, 1),
-      jnp.asarray(b, jnp.float32).reshape(1, 1))
+    )(x, seeds.astype(jnp.int32),
+      jnp.asarray(a, jnp.float32).reshape(1),
+      jnp.asarray(b, jnp.float32).reshape(1))

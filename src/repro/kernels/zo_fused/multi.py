@@ -43,8 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS, _pin,
-                                           _tile_affine, z_from_counter)
+from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS, SMEM_SPEC,
+                                           _pin, _tile_affine, z_from_counter)
 
 
 # --------------------------------------------------------------------------- #
@@ -55,10 +55,10 @@ def _zo_affine_multi_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # Grid is (row_blocks, batch): row-block axis OUTER, so the x tile for
     # row-block i stays resident while the inner batch axis walks the B
     # (seed_j, a_j, b_j) triples against it.  Same structure as PR 3's
-    # batched kernel; the per-stream a/b BlockSpecs are the generalization.
-    i = pl.program_id(0)
-    seed = seed_ref[0, 0].astype(jnp.uint32)
-    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0, 0], b_ref[0, 0],
+    # batched kernel; the per-stream a/b reads are the generalization.
+    i, j = pl.program_id(0), pl.program_id(1)
+    seed = seed_ref[j].astype(jnp.uint32)
+    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[j], b_ref[j],
                      interpret, dist)
     o_ref[0, ...] = y.astype(o_ref.dtype)
 
@@ -83,16 +83,13 @@ def zo_affine_multi_2d(x: jnp.ndarray, seeds: jnp.ndarray, a: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((1, BLOCK_ROWS, cols), lambda i, j: (j, i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, rows, cols), x.dtype),
         interpret=interpret,
-    )(x, seeds.reshape(-1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(-1, 1),
-      jnp.asarray(b, jnp.float32).reshape(-1, 1))
+    )(x, seeds.astype(jnp.int32), jnp.asarray(a, jnp.float32).reshape(-1),
+      jnp.asarray(b, jnp.float32).reshape(-1))
 
 
 # --------------------------------------------------------------------------- #
@@ -111,8 +108,8 @@ def _zo_affine_chain_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
     i = pl.program_id(0)
     y = x_ref[...]
     for j in range(n_streams):
-        seed = seed_ref[j, 0].astype(jnp.uint32)
-        y = _tile_affine(y, i, cols, seed, a_ref[j, 0], b_ref[j, 0],
+        seed = seed_ref[j].astype(jnp.uint32)
+        y = _tile_affine(y, i, cols, seed, a_ref[j], b_ref[j],
                          interpret, dist).astype(x_ref.dtype)
     o_ref[...] = y
 
@@ -141,16 +138,13 @@ def zo_affine_chain_2d(x: jnp.ndarray, seeds: jnp.ndarray, a: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
-            pl.BlockSpec((int(batch), 1), lambda i: (0, 0)),
-            pl.BlockSpec((int(batch), 1), lambda i: (0, 0)),
-            pl.BlockSpec((int(batch), 1), lambda i: (0, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
-    )(x, seeds.reshape(-1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(-1, 1),
-      jnp.asarray(b, jnp.float32).reshape(-1, 1))
+    )(x, seeds.astype(jnp.int32), jnp.asarray(a, jnp.float32).reshape(-1),
+      jnp.asarray(b, jnp.float32).reshape(-1))
 
 
 # --------------------------------------------------------------------------- #
@@ -173,16 +167,16 @@ def _sqnorm_tile(row_block, cols: int, seed: jnp.ndarray, n: int,
 def _zo_sqnorm_kernel(seed_ref, o_ref, *, cols: int, n: int,
                       interpret: bool, dist: str):
     i = pl.program_id(0)
-    seed = seed_ref[0, 0].astype(jnp.uint32)
+    seed = seed_ref[0].astype(jnp.uint32)
     part = _sqnorm_tile(i, cols, seed, n, dist, pin=interpret)
 
     @pl.when(i == 0)
     def _init():
-        o_ref[0, 0] = part
+        o_ref[0] = part
 
     @pl.when(i > 0)
     def _acc():
-        o_ref[0, 0] = o_ref[0, 0] + part
+        o_ref[0] = o_ref[0] + part
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret", "dist"))
@@ -191,21 +185,21 @@ def zo_sqnorm_2d(n: int, seed, interpret: bool = True,
     """‖z(seed)[0:n]‖² as one f32 scalar: pass 1 of the two-pass sphere
     rescale.  The z stream is generated tile-by-tile (never materialized in
     HBM) and the per-tile partial sums accumulate across sequential grid
-    steps into a single (1, 1) output block — the counter indices are the
-    same global element positions the affine kernels use, so pass 2 rescales
-    exactly the z this pass measured.  ``n`` (static) masks the padding tail
-    of the blocked view out of the norm."""
+    steps into a single SMEM scalar, in tile order — the counter indices are
+    the same global element positions the affine kernels use, so pass 2
+    rescales exactly the z this pass measured.  ``n`` (static) masks the
+    padding tail of the blocked view out of the norm."""
     width = BLOCK_ROWS * BLOCK_COLS
     blocks = max(1, -(-int(n) // width))
     return pl.pallas_call(
         functools.partial(_zo_sqnorm_kernel, cols=BLOCK_COLS, n=int(n),
                           interpret=interpret, dist=dist),
         grid=(blocks,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        in_specs=[SMEM_SPEC],
+        out_specs=SMEM_SPEC,
+        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(seed, jnp.int32).reshape(1, 1))[0, 0]
+    )(jnp.asarray(seed, jnp.int32).reshape(1))[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "dist"))

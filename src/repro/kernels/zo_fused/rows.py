@@ -13,8 +13,8 @@ blocks**:
   kernel, and generate no z (the trace-time skip of PR 5's leaf semantics,
   one level down);
 * selected tiles are gathered into a compact (n_sel·256, 512) operand, the
-  kernel grids over the *compact* axis, and each grid step receives its
-  original tile index through a scalar input — ``_tile_affine`` then derives
+  kernel grids over the *compact* axis, and each grid step reads its
+  original tile index from an SMEM tile-id vector — ``_tile_affine`` derives
   counter indices from the **global** element position exactly as the full
   kernel does, so a selected tile's z bits are identical whether the leaf is
   perturbed whole or block-by-block (the blocked StreamRef index contract);
@@ -29,7 +29,8 @@ Why a compact gather instead of a scalar-prefetch index map: the
 shape around the z generator, and (as ``_pin``'s docstring warns) LLVM-level
 FMA contraction after barrier erasure then breaks the 1-ulp bitwise contract
 against the full kernel.  The compact form reuses the exact BlockSpec
-machinery of ``kernel.py`` — bitwise equality is structural.
+machinery of ``kernel.py`` (x tiles in VMEM, tile ids / seeds / coefficients
+as whole SMEM vectors) — bitwise equality is structural.
 
 All variants share ``_tile_affine`` / ``z_from_counter`` with the full
 kernels; the bitwise selected-tiles ≡ full-kernel contract is those functions
@@ -43,8 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS, _pin,
-                                           _tile_affine, z_from_counter)
+from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS, SMEM_SPEC,
+                                           _pin, _tile_affine, z_from_counter)
 
 TILE_ELEMS = BLOCK_ROWS * BLOCK_COLS
 
@@ -118,7 +119,7 @@ def _scatter_tiles(x: jnp.ndarray, y: jnp.ndarray, sel: tuple) -> jnp.ndarray:
 
 
 def _tiles_input(sel: tuple) -> jnp.ndarray:
-    return jnp.asarray(sel, jnp.int32).reshape(-1, 1)
+    return jnp.asarray(sel, jnp.int32)
 
 
 # --------------------------------------------------------------------------- #
@@ -130,10 +131,10 @@ def _zo_affine_rows_kernel(x_ref, tile_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # the grid walks the COMPACT tile axis; the original tile index arrives
     # as data, so _tile_affine's global counter base — and therefore the z
     # bits — match the full-grid kernel exactly
-    t = tile_ref[0, 0]
-    seed = seed_ref[0, 0].astype(jnp.uint32)
+    t = tile_ref[pl.program_id(0)]
+    seed = seed_ref[0].astype(jnp.uint32)
     x = x_ref[...]
-    y = _tile_affine(x, t, cols, seed, a_ref[0, 0], b_ref[0, 0],
+    y = _tile_affine(x, t, cols, seed, a_ref[0], b_ref[0],
                      interpret, dist).astype(o_ref.dtype)
     if masked:
         y = jnp.where(_tile_sel_mask(t, cols, block_elems, k, phase), y, x)
@@ -166,17 +167,14 @@ def zo_affine_2d_rows(x: jnp.ndarray, seed: jnp.ndarray, a: jnp.ndarray,
         grid=(n_sel,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xs.shape, x.dtype),
         interpret=interpret,
-    )(xs, _tiles_input(sel), seed.reshape(1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(1, 1),
-      jnp.asarray(b, jnp.float32).reshape(1, 1))
+    )(xs, _tiles_input(sel), seed.reshape(1).astype(jnp.int32),
+      jnp.asarray(a, jnp.float32).reshape(1),
+      jnp.asarray(b, jnp.float32).reshape(1))
     return _scatter_tiles(x, y, sel)
 
 
@@ -190,10 +188,11 @@ def _zo_affine_multi_rows_kernel(x_ref, tile_ref, seed_ref, a_ref, b_ref,
     # grid (n_sel, batch): compact tile axis OUTER so the x tile stays
     # resident while the inner batch axis walks the B streams against it —
     # the multi.py structure over the compact operand
-    t = tile_ref[0, 0]
-    seed = seed_ref[0, 0].astype(jnp.uint32)
+    t = tile_ref[pl.program_id(0)]
+    j = pl.program_id(1)
+    seed = seed_ref[j].astype(jnp.uint32)
     x = x_ref[...]
-    y = _tile_affine(x, t, cols, seed, a_ref[0, 0], b_ref[0, 0],
+    y = _tile_affine(x, t, cols, seed, a_ref[j], b_ref[j],
                      interpret, dist).astype(o_ref.dtype)
     if masked:
         y = jnp.where(_tile_sel_mask(t, cols, block_elems, k, phase), y, x)
@@ -224,18 +223,15 @@ def zo_affine_multi_2d_rows(x: jnp.ndarray, seeds: jnp.ndarray,
         grid=(n_sel, batch),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((1, BLOCK_ROWS, cols), lambda i, j: (j, i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, n_sel * BLOCK_ROWS, cols),
                                        x.dtype),
         interpret=interpret,
-    )(xs, _tiles_input(sel), seeds.reshape(-1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(-1, 1),
-      jnp.asarray(b, jnp.float32).reshape(-1, 1))
+    )(xs, _tiles_input(sel), seeds.astype(jnp.int32),
+      jnp.asarray(a, jnp.float32).reshape(-1),
+      jnp.asarray(b, jnp.float32).reshape(-1))
     out = jnp.broadcast_to(x, (batch,) + x.shape)
     for j, t in enumerate(sel):
         out = jax.lax.dynamic_update_slice(
@@ -255,12 +251,12 @@ def _zo_affine_chain_rows_kernel(x_ref, tile_ref, seed_ref, a_ref, b_ref,
     # elements' values never depend on unselected neighbours) and the block
     # predicate restores x's bits once at the end — equivalent to masking
     # every fold step, at one select instead of n_streams
-    t = tile_ref[0, 0]
+    t = tile_ref[pl.program_id(0)]
     x = x_ref[...]
     y = x
     for j in range(n_streams):
-        seed = seed_ref[j, 0].astype(jnp.uint32)
-        y = _tile_affine(y, t, cols, seed, a_ref[j, 0], b_ref[j, 0],
+        seed = seed_ref[j].astype(jnp.uint32)
+        y = _tile_affine(y, t, cols, seed, a_ref[j], b_ref[j],
                          interpret, dist).astype(x_ref.dtype)
     if masked:
         y = jnp.where(_tile_sel_mask(t, cols, block_elems, k, phase), y, x)
@@ -292,17 +288,14 @@ def zo_affine_chain_2d_rows(x: jnp.ndarray, seeds: jnp.ndarray,
         grid=(n_sel,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((int(batch), 1), lambda i: (0, 0)),
-            pl.BlockSpec((int(batch), 1), lambda i: (0, 0)),
-            pl.BlockSpec((int(batch), 1), lambda i: (0, 0)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xs.shape, x.dtype),
         interpret=interpret,
-    )(xs, _tiles_input(sel), seeds.reshape(-1, 1).astype(jnp.int32),
-      jnp.asarray(a, jnp.float32).reshape(-1, 1),
-      jnp.asarray(b, jnp.float32).reshape(-1, 1))
+    )(xs, _tiles_input(sel), seeds.astype(jnp.int32),
+      jnp.asarray(a, jnp.float32).reshape(-1),
+      jnp.asarray(b, jnp.float32).reshape(-1))
     return _scatter_tiles(x, y, sel)
 
 
@@ -329,18 +322,18 @@ def _zo_sqnorm_rows_kernel(tile_ref, seed_ref, o_ref, *, cols: int, n: int,
                            block_elems: int, k: int, phase: int,
                            interpret: bool, dist: str):
     i = pl.program_id(0)
-    t = tile_ref[0, 0]
-    seed = seed_ref[0, 0].astype(jnp.uint32)
+    t = tile_ref[i]
+    seed = seed_ref[0].astype(jnp.uint32)
     part = _sqnorm_rows_tile(t, cols, seed, n, block_elems, k, phase, dist,
                              pin=interpret)
 
     @pl.when(i == 0)
     def _init():
-        o_ref[0, 0] = part
+        o_ref[0] = part
 
     @pl.when(i > 0)
     def _acc():
-        o_ref[0, 0] = o_ref[0, 0] + part
+        o_ref[0] = o_ref[0] + part
 
 
 @functools.partial(jax.jit, static_argnames=("n", "sel", "block_elems", "k",
@@ -357,14 +350,11 @@ def zo_sqnorm_2d_rows(n: int, seed, sel: tuple, block_elems: int, k: int,
                           block_elems=int(block_elems), k=int(k),
                           phase=int(phase), interpret=interpret, dist=dist),
         grid=(len(sel),),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        in_specs=[SMEM_SPEC, SMEM_SPEC],
+        out_specs=SMEM_SPEC,
+        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         interpret=interpret,
-    )(_tiles_input(sel), jnp.asarray(seed, jnp.int32).reshape(1, 1))[0, 0]
+    )(_tiles_input(sel), jnp.asarray(seed, jnp.int32).reshape(1))[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "sel", "block_elems", "k",

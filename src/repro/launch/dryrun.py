@@ -23,7 +23,6 @@ import time
 import traceback
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import flops as flops_lib
@@ -130,8 +129,6 @@ def _compile_case(cfg, b, cell, mesh, donate: bool = True,
 def _cost_triple(compiled):
     """(flops, hbm_bytes, collective_bytes) per chip from a compiled exe."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     try:
         coll = roofline_lib.collective_stats(compiled.as_text())
     except Exception:

@@ -10,21 +10,33 @@ serves a skewed request mix across all of them through ONE engine —
 materialized deltas ride a byte-budgeted LRU (``--cache-mb``) and long
 ledgers can be folded to delta + tail (``--compact-tail``); see
 ``repro.serve.tenants``.
+
+``main(argv)`` can be driven in-process; in single-model mode it returns a
+:class:`ServeRun` (the engine and the served requests, whose ``out_ids`` hold
+the generated tokens).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
 
 import jax
 
 from repro.core import TrajectoryLedger, replay
+from repro.launch.cache import enable_compile_cache
 from repro.models import all_archs, bundle
 from repro.serve.engine import Request, ServeEngine
 
 
-def main():
+@dataclasses.dataclass
+class ServeRun:
+    engine: ServeEngine
+    requests: list
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true")
@@ -50,18 +62,26 @@ def main():
                     help="KV pool size in blocks (default: 2x slot demand)")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable the radix prefix cache (paged pool stays)")
+    ap.add_argument("--gather", default="auto",
+                    choices=["auto", "xla", "pallas"],
+                    help="paged KV gather: XLA advanced indexing or the "
+                         "kernels/paged pallas kernel ('auto' follows "
+                         "REPRO_BACKEND, default xla)")
     ap.add_argument("--templates", type=int, default=0,
                     help="tenant mode: draw prompts from N shared task "
                          "templates per tenant (Zipf) instead of fully "
                          "random prompts — exercises the prefix cache")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = all_archs()[args.arch]
     cfg = arch.smoke_cfg if args.smoke else arch.cfg
     b = bundle(cfg)
     params = b.init(jax.random.PRNGKey(args.seed))
-    if args.ledger and os.path.exists(args.ledger):
+    if args.ledger:
+        if not os.path.exists(args.ledger):
+            raise SystemExit(f"--ledger {args.ledger!r} does not exist")
         with open(args.ledger, "rb") as f:
             led = TrajectoryLedger.from_bytes(f.read())
         # the ledger header records the run's full seed-schedule coordinates
@@ -76,11 +96,13 @@ def main():
     engine = ServeEngine(cfg, params, slots=args.slots, max_len=args.max_len,
                          seed=args.seed, block=args.block,
                          pool_blocks=args.pool_blocks,
-                         prefix_cache=not args.no_prefix_cache)
+                         prefix_cache=not args.no_prefix_cache,
+                         gather_impl=args.gather)
     if engine.paged:
         print(f"[serve] paged KV: block={args.block} tokens, "
               f"pool={engine.pool.n_blocks} blocks, prefix cache "
-              f"{'off' if args.no_prefix_cache else 'on'}")
+              f"{'off' if args.no_prefix_cache else 'on'}, "
+              f"{'pallas' if engine._gather_pallas else 'xla'} gather")
 
     if args.tenants > 0:
         from repro.serve.tenants import (lora_runtime, make_lora_tenants,
@@ -160,6 +182,7 @@ def main():
               f"hit rate {ps['prefix_hit_rate']:.2f}")
     for r in reqs[:4]:
         print(f"  req {r.rid}: {r.prompt_ids} -> {r.out_ids}")
+    return ServeRun(engine, reqs)
 
 
 if __name__ == "__main__":

@@ -5,26 +5,58 @@ backprop-Adam baseline), step-indexed data, checkpoint manager + scalar
 ledger, heartbeat.  On a real cluster each host runs this with
 ``jax.distributed.initialize`` handled by the scheduler; the step function
 and data pipeline are already multi-host-safe (pure step-indexed batches,
-pjit-ready shardings from repro.distributed).
+pjit-ready shardings from repro.distributed).  ``--exec-plan seed_parallel``
+on a host with several devices puts group g's batch slice on device g of a
+``data`` mesh (``StepProgram.shardings``).
+
+``main(argv)`` can be driven in-process and returns a :class:`TrainRun`.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Any, Optional
 
 import jax
+import numpy as np
+from jax.sharding import Mesh
 
 from repro import exec as zexec
 from repro import zo
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import TrajectoryLedger
 from repro.data.pipeline import DataSpec, Pipeline
+from repro.launch.cache import enable_compile_cache
 from repro.models import FAMILY_ARCHS, OBJECTIVES, all_archs, bundle
 from repro.train.adam import Adam, AdamConfig
-from repro.train.loop import HeartbeatMonitor, train
+from repro.train.loop import HeartbeatMonitor, TrainResult, train
 from repro.tree_utils import tree_size
 
 
-def main():
+@dataclasses.dataclass
+class TrainRun:
+    """What ``main`` built and ran: enough to replay the ledger onto the
+    initial parameters (``bundle.init(PRNGKey(seed))``) and compare."""
+    cfg: Any
+    bundle: Any
+    optimizer: Any                # a StepProgram or a bare optimizer
+    pipeline: Pipeline
+    ledger: Optional[TrajectoryLedger]
+    result: TrainResult
+
+
+def group_mesh(n_groups: int) -> Optional[Mesh]:
+    """A ``data`` mesh holding one seed group per device, or ``None`` when
+    the host has fewer devices than groups (or one group): the groups then
+    run in turn on one device."""
+    devices = jax.devices()
+    if n_groups == 1 or n_groups > len(devices):
+        return None
+    return Mesh(np.array(devices[:n_groups]).reshape(n_groups, 1),
+                ("data", "model"))
+
+
+def main(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--model-family", default=None,
@@ -95,7 +127,8 @@ def main():
                     help="use the arch's reduced smoke config")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-interval", type=int, default=100)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.model_family is not None:
         args.arch = FAMILY_ARCHS[args.model_family]
@@ -170,8 +203,11 @@ def main():
         if args.batch % args.n_groups:
             raise SystemExit(f"--batch {args.batch} must divide evenly into "
                              f"--n-groups {args.n_groups} slices")
-        opt = zexec.StepProgram(opt, zexec.seed_parallel(args.n_groups))
-        print(f"[train] exec plan: seed_parallel(n_groups={args.n_groups})")
+        mesh = group_mesh(args.n_groups)
+        opt = zexec.StepProgram(opt, zexec.seed_parallel(args.n_groups,
+                                                         mesh=mesh))
+        print(f"[train] exec plan: seed_parallel(n_groups={args.n_groups})"
+              + (f" on a data={args.n_groups} mesh" if mesh else ""))
 
     ckpt = (CheckpointManager(args.ckpt_dir, interval=args.ckpt_interval)
             if args.ckpt_dir else None)
@@ -186,6 +222,7 @@ def main():
     if ledger is not None:
         print(f"[train] ledger: {len(ledger)} entries, "
               f"{ledger.nbytes()} bytes")
+    return TrainRun(cfg, b, opt, pipe, ledger, res)
 
 
 if __name__ == "__main__":

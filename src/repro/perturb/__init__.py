@@ -29,17 +29,19 @@ instead of silently reconstructing different parameters.
     The fused Pallas kernel: z is generated tile-by-tile **inside VMEM** from
     a counter hash of (seed, element index) and never exists in HBM at all —
     perturb/update is one read-modify-write stream over the parameters at
-    pure memory-bandwidth speed, with zero z traffic.  On TPU it runs
-    compiled; off-TPU it transparently falls back to Pallas interpret mode
-    (identical arithmetic, jnp-evaluated) so CPU runs and CI exercise the
-    same stream.  Supports gaussian and rademacher (sign of one counter
-    stream, generated in-kernel) — sphere raises ``NotImplementedError``
-    (see the matrix in ``repro.perturb.base``).
+    pure memory-bandwidth speed, with zero z traffic.  On a TPU it runs
+    compiled; on the CPU it runs in Pallas interpret mode (identical
+    arithmetic, jnp-evaluated) so CPU runs and CI exercise the same stream;
+    on any other platform it raises rather than interpret in silence.
+    Supports gaussian, rademacher (sign of one counter stream, generated
+    in-kernel) and sphere (the gaussian stream rescaled by a kernel-measured
+    ‖z‖; see the matrix in ``repro.perturb.base``).
 
 ``backend="pallas-interpret"``
-    Same stream as ``pallas`` with interpret mode forced — for measuring
-    interpreter overhead (``benchmarks/bench_perturb.py``) and for debugging
-    kernel semantics under jnp.
+    Same stream as ``pallas`` with interpret mode forced, on any platform —
+    the only way to interpret on a TPU.  For measuring interpreter overhead
+    (``benchmarks/bench_perturb.py``) and for debugging kernel semantics
+    under jnp.
 
 The two backends generate *different* (both valid N(0,1)) z streams for the
 same ``StreamRef``; within a backend the stream is bitwise-stable across

@@ -239,7 +239,7 @@ def get_backend(spec: BackendSpec = None) -> PerturbBackend:
 
     The env hook exists for the CI matrix: ``REPRO_BACKEND=pallas pytest``
     runs every composition that didn't pin a backend through the fused
-    kernel (interpret mode off-TPU), so the non-default backend is exercised
+    kernel (interpret mode on the CPU), so the non-default backend is exercised
     on every push without a parallel test tree."""
     if spec is None:
         spec = os.environ.get("REPRO_BACKEND") or "xla"

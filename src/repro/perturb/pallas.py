@@ -14,11 +14,12 @@ tree around it changes or how leaves are padded to the kernel's blocked view.
 The pure-jnp oracle in ``kernels/zo_fused/ref.py`` implements the identical
 arithmetic bit-for-bit.
 
-Interpret-mode fallback: off-TPU the kernel runs under
+Interpret mode: on the CPU the kernel runs under
 ``pallas_call(..., interpret=True)`` (exact same arithmetic, evaluated with
 jnp ops), so CPU CI and laptops exercise the real backend semantics.
-``get_backend("pallas")`` auto-selects interpret off-TPU;
-``get_backend("pallas-interpret")`` forces it (for benchmarking the overhead).
+``get_backend("pallas")`` compiles on a TPU, interprets on the CPU and raises
+on any other platform (``repro.kernels.interpret_default``);
+``get_backend("pallas-interpret")`` forces interpret mode by name.
 
 Supported distributions: gaussian (Box–Muller), rademacher (the sign of one
 counter stream, generated in-kernel), and sphere — the kernel-fused two-pass
@@ -42,6 +43,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS,
                                            zo_affine_2d, zo_affine_2d_batched)
 from repro.kernels.zo_fused.multi import (zo_affine_chain_2d,
@@ -226,7 +228,7 @@ def mezo_step_kernel(loss_fn, params: PyTree, batch, seed: int, eps: float,
 # Backend adapter
 # --------------------------------------------------------------------------- #
 class PallasBackend(PerturbBackend):
-    """Fused-kernel z streams: VMEM generation on TPU, interpret mode off-TPU.
+    """Fused-kernel z streams: VMEM generation on TPU, interpret mode on CPU.
 
     Selection-aware: a ``StreamRef`` carrying a ``repro.select.Selection``
     scopes every method to the selected leaves — unselected leaves get no
@@ -244,7 +246,7 @@ class PallasBackend(PerturbBackend):
 
     def __init__(self, interpret: Optional[bool] = None):
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_default()
         self.interpret = bool(interpret)
 
     def _pin_scalars(self, *vals):

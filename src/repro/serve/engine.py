@@ -317,8 +317,8 @@ class ServeEngine:
         xk = self.pool.k.reshape(L, NT, KV * hd)
         xv = self.pool.v.reshape(L, NT, KV * hd)
         if self._gather_pallas:
-            from repro.kernels.paged import paged_gather
-            interpret = jax.default_backend() != "tpu"
+            from repro.kernels import interpret_default, paged_gather
+            interpret = interpret_default()
             gk = paged_gather(xk, flat, self.block, interpret=interpret)
             gv = paged_gather(xv, flat, self.block, interpret=interpret)
         else:

@@ -169,8 +169,17 @@ def train(loss_fn: Callable, params: PyTree, optimizer, pipeline: Pipeline,
             # with wherever resume landed — the protocol's resume hook
             opt_state = program.restore(opt_state, start_step)
 
+    jit_kw = {}
+    if program.plan.mesh is not None:
+        # a mesh plan (seed_parallel over devices): θ and the state
+        # replicated, each device's batch slice resident on it
+        pshard, sshard, bshard = program.shardings(
+            params, pipeline.batch(start_step), opt_state)
+        params = jax.device_put(params, pshard)
+        opt_state = jax.device_put(opt_state, sshard)
+        jit_kw["in_shardings"] = (pshard, sshard, bshard)
     step_fn = jax.jit(program.step_fn(loss_fn),
-                      donate_argnums=(0,) if donate else ())
+                      donate_argnums=(0,) if donate else (), **jit_kw)
     losses = []
     t0 = time.time()
     for step in range(start_step, total_steps):

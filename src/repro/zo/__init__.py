@@ -72,7 +72,7 @@ and new update rules plug in as components — one ``ZOEstimator`` or one
 ``ZOTransform``, not a new monolithic optimizer class.  Every composition
 takes a ``backend=`` kwarg selecting the z-generation strategy
 (:mod:`repro.perturb`): ``"xla"`` threefry (default) or ``"pallas"`` — the
-fused kernel generating z inside VMEM, with interpret-mode CPU fallback.
+fused kernel generating z inside VMEM, in interpret mode on the CPU.
 The choice is recorded in checkpoint/ledger metadata; replay under the wrong
 backend raises ``BackendMismatchError`` instead of silently diverging.
 """

@@ -46,8 +46,8 @@ def mezo(lr: float = 1e-6, eps: float = 1e-3, n: int = 1,
                     chain(clip?, scale_by_schedule(lr), add_weight_decay?))
 
     ``backend`` selects the z-generation strategy (``"xla"`` threefry HBM
-    temporaries, ``"pallas"`` VMEM-fused kernel with interpret-mode CPU
-    fallback) — see :mod:`repro.perturb`.  ``selection`` scopes the
+    temporaries, ``"pallas"`` VMEM-fused kernel, interpret mode on the
+    CPU) — see :mod:`repro.perturb`.  ``selection`` scopes the
     perturbation/update to a parameter subset (``repro.select.Selection`` or
     spec string, e.g. ``"block_cyclic(4)"`` or ``select.peft("lora")``).
     """
