@@ -22,7 +22,7 @@ from repro.core import TrajectoryLedger
 from repro.data.pipeline import DataSpec, Pipeline
 from repro.models import bundle
 from repro.models.config import ModelConfig
-from repro.train.loop import HeartbeatMonitor, train
+from repro.train.loop import train
 from repro.tree_utils import tree_size
 
 
@@ -117,8 +117,7 @@ def main():
     ledger = TrajectoryLedger(base_seed=0, grad_dtype="float32")
 
     result = train(b.loss_fn(), params, opt, pipe, total_steps=args.steps,
-                   ckpt=ckpt, ledger=ledger, monitor=HeartbeatMonitor(),
-                   log_every=20, verbose=True)
+                   ckpt=ckpt, ledger=ledger, log_every=20, verbose=True)
     print(f"ran {result.steps_run} steps (resumed from {result.resumed_from})")
     print(f"loss trajectory: {[f'{l:.3f}' for _, l in result.losses[:8]]} ...")
     print(f"ledger: {len(ledger)} scalar entries = {ledger.nbytes()} bytes "

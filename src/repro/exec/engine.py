@@ -37,6 +37,7 @@ from repro.exec.plan import ExecPlan, check_replay_plan
 from repro.perturb import StreamRef, check_replay_backend, step_key
 from repro.select import check_replay_selection
 from repro.tree_utils import PyTree
+from repro.zo import phases
 from repro.zo.base import TransformCtx, Updates, ZOState
 from repro.zo.presets import as_zo_optimizer
 from repro.zo.updates import apply_rank1_batch
@@ -72,14 +73,15 @@ def apply_group_update(params: PyTree, skey0: jax.Array, group: int,
     ``phase`` scope the update to the step's selected leaves — the phase is
     the STEP's (a pure function of t), shared by every group of the step."""
     gkey = group_key(skey0, group, n_groups)
-    if batch_seeds == 1:
-        ref = StreamRef(gkey)
-        if selection is not None:
-            ref = ref.with_selection(selection, phase)
-        return backend.apply_rank1(params, ref, coeff, decay_term, dist)
-    return apply_rank1_batch(params, gkey, coeff, decay_term, dist,
-                             backend=backend, selection=selection,
-                             phase=phase)
+    with jax.named_scope(phases.UPDATE):
+        if batch_seeds == 1:
+            ref = StreamRef(gkey)
+            if selection is not None:
+                ref = ref.with_selection(selection, phase)
+            return backend.apply_rank1(params, ref, coeff, decay_term, dist)
+        return apply_rank1_batch(params, gkey, coeff, decay_term, dist,
+                                 backend=backend, selection=selection,
+                                 phase=phase)
 
 
 def apply_group_updates(params: PyTree, skey0: jax.Array, coeffs: Sequence,
@@ -115,7 +117,8 @@ def apply_group_updates(params: PyTree, skey0: jax.Array, coeffs: Sequence,
                 refs.append(ref)
                 cs.append(cvec[j] / batch_seeds)
                 ds.append(decay_g if j == 0 else 0.0)
-    return backend.affine_many(params, refs, cs, ds, dist)
+    with jax.named_scope(phases.UPDATE):
+        return backend.affine_many(params, refs, cs, ds, dist)
 
 
 def slice_group(batch, group: int, n_groups: int):
@@ -293,17 +296,16 @@ class StepProgram:
         its batch slice, and its transform output.  ``phase`` is None when
         the selection schedule has a single phase."""
         est, tf = self.opt.estimator, self.opt.transform
-        if phase is None:
-            e = est.estimate(loss_fn, params, batch, skey, est_state)
-        else:
-            e = est.estimate(loss_fn, params, batch, skey, est_state,
-                             phase=phase)
+        e = phases.estimate(est, loss_fn, params, batch, skey, est_state,
+                            phase)
         ctx = TransformCtx(step=state.step, base_key=state.base_key,
                            key=skey, seed_index=seed_index,
                            n_seeds=self.plan.n_groups, eps=est.eps,
                            dist=est.dist, restore=e.restore,
                            backend=self.opt.backend)
-        u, tf_state = tf.update(Updates(g=e.projected_grad), tf_state, ctx)
+        with jax.named_scope(phases.UPDATE):
+            u, tf_state = tf.update(Updates(g=e.projected_grad), tf_state,
+                                    ctx)
         if u.final_params is not None:
             # unreachable behind the __init__ applier guard; loud (not
             # silently dropped) if that guard is ever relaxed
@@ -367,7 +369,8 @@ class StepProgram:
                 est_state = e.est_state
                 # evaluations stay at the step's center; directions are
                 # averaged afterwards through the shared write path
-                p = e.restore()
+                with jax.named_scope(phases.UPDATE):
+                    p = e.restore()
                 coeffs.append(u.coeff if u.coeff is not None else u.g)
                 if g == 0:
                     decay0 = u.decay
@@ -400,7 +403,9 @@ class StepProgram:
                                        axis_name=group_axis)
             coeffs = gather(u.coeff if u.coeff is not None else u.g)
             aux = {k: gather(v)[n - 1] for k, v in (e.aux or {}).items()}
-            p = apply_group_updates(e.restore(), skey0,
+            with jax.named_scope(phases.UPDATE):
+                center = e.restore()
+            p = apply_group_updates(center, skey0,
                                     [coeffs[i] for i in range(n)], u.decay,
                                     n, batch_seeds, est.dist, backend,
                                     selection=sel, phase=phase)
@@ -491,16 +496,15 @@ class StepProgram:
             skey = group_stream_key(base_key, step, worker, n)
             e_state = (est_state if est_state is not None
                        else est.init(None, base_key))
-            if sel is None:
-                e = est.estimate(loss_fn, params, batch, skey, e_state)
-            else:
-                e = est.estimate(loss_fn, params, batch, skey, e_state,
-                                 phase=phase)
+            e = phases.estimate(est, loss_fn, params, batch, skey, e_state,
+                                None if sel is None else phase)
             ctx = TransformCtx(step=step, base_key=base_key, key=skey,
                                seed_index=worker, n_seeds=n, eps=est.eps,
                                dist=est.dist, restore=e.restore,
                                backend=opt.backend)
-            u, _ = tf.update(Updates(g=e.projected_grad), tf.init(None), ctx)
+            with jax.named_scope(phases.UPDATE):
+                u, _ = tf.update(Updates(g=e.projected_grad), tf.init(None),
+                                 ctx)
             lr = u.lr if u.lr is not None else jnp.float32(1.0)
             return u.g, lr, e.loss
 

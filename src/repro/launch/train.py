@@ -29,7 +29,7 @@ from repro.data.pipeline import DataSpec, Pipeline
 from repro.launch.cache import enable_compile_cache
 from repro.models import FAMILY_ARCHS, OBJECTIVES, all_archs, bundle
 from repro.train.adam import Adam, AdamConfig
-from repro.train.loop import HeartbeatMonitor, TrainResult, train
+from repro.train.loop import TrainResult, train
 from repro.tree_utils import tree_size
 
 
@@ -212,8 +212,7 @@ def main(argv=None) -> TrainRun:
     ckpt = (CheckpointManager(args.ckpt_dir, interval=args.ckpt_interval)
             if args.ckpt_dir else None)
     res = train(b.loss_fn(objective=args.objective), params, opt, pipe,
-                total_steps=args.steps,
-                ckpt=ckpt, ledger=ledger, monitor=HeartbeatMonitor(),
+                total_steps=args.steps, ckpt=ckpt, ledger=ledger,
                 log_every=max(args.steps // 10, 1), verbose=True,
                 seed=args.seed)
     print(f"[train] done: {res.steps_run} steps "

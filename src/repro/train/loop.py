@@ -1,4 +1,4 @@
-"""Checkpointed training loop with fault-tolerance hooks.
+"""Checkpointed training loop with a failure-injection hook.
 
 Responsibilities:
   * jit + donate the optimizer step once;
@@ -6,8 +6,7 @@ Responsibilities:
   * full checkpoints every K steps + per-step ZO scalar ledger;
   * resume: newest full ckpt, then *ledger replay* of the tail — the
     replacement worker rejoins bitwise-identically without data access;
-  * straggler/failure hooks: a HeartbeatMonitor ABC the launcher wires to
-    its process manager; ``FailureInjector`` drives the chaos tests.
+  * ``FailureInjector`` drives the chaos tests.
 
 The loop is execution-engine-aware but optimizer-agnostic: ``optimizer`` is a
 ``repro.exec.StepProgram`` (any ``repro.zo`` composition lowered onto any
@@ -44,23 +43,6 @@ from repro.select import check_replay_selection
 from repro.tree_utils import PyTree
 
 
-class HeartbeatMonitor:
-    """Launcher-facing hook: the loop beats every step; deployments override
-    ``on_beat`` to feed a watchdog (k8s liveness, SLURM requeue, etc.)."""
-
-    def __init__(self, timeout_s: float = 300.0):
-        self.timeout_s = timeout_s
-        self.last = time.monotonic()
-
-    def beat(self, step: int) -> None:
-        now = time.monotonic()
-        self.on_beat(step, now - self.last)
-        self.last = now
-
-    def on_beat(self, step: int, dt: float) -> None:  # pragma: no cover
-        pass
-
-
 class FailureInjector:
     """Test hook: raise at a chosen step to simulate a node crash."""
 
@@ -84,7 +66,6 @@ class TrainResult:
 def train(loss_fn: Callable, params: PyTree, optimizer, pipeline: Pipeline,
           total_steps: int, ckpt: Optional[CheckpointManager] = None,
           ledger: Optional[TrajectoryLedger] = None,
-          monitor: Optional[HeartbeatMonitor] = None,
           injector: Optional[FailureInjector] = None,
           log_every: int = 50, donate: bool = True,
           eval_fn: Optional[Callable] = None, eval_every: int = 0,
@@ -206,8 +187,6 @@ def train(loss_fn: Callable, params: PyTree, optimizer, pipeline: Pipeline,
                 ckpt.save_ledger(ledger)
         if ckpt is not None:
             ckpt.maybe_save(step + 1, params, opt_state, meta=meta)
-        if monitor is not None:
-            monitor.beat(step)
         if step % log_every == 0 or step == total_steps - 1:
             losses.append((step, float(metrics["loss"])))
             if verbose:

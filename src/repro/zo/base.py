@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.perturb import PerturbBackend, StreamRef, get_backend, step_key
 from repro.tree_utils import PyTree
+from repro.zo import phases
 
 ZOLossFn = Callable[[PyTree, Any], jnp.ndarray]
 
@@ -333,21 +334,23 @@ class ZOOptimizer:
                 "along D·z (Definition 6), which a (seed, g, lr) ledger entry "
                 "cannot reproduce; resume from a full state checkpoint")
         sel = self.selection
-        if self.batch_seeds > 1:
-            # batched-seed (FZOO) entry: g is the (B,) per-seed vector and the
-            # step was B folded rank-1 applications — replay them identically
-            from repro.zo.updates import apply_rank1_batch
-            return apply_rank1_batch(params, skey, lr * jnp.asarray(g),
-                                     lr * self.weight_decay,
-                                     dist=self.estimator.dist,
-                                     backend=self.backend,
-                                     selection=sel, phase=phase)
-        ref = StreamRef(skey)
-        if sel is not None:
-            ref = ref.with_selection(sel, phase)
-        return self.backend.apply_rank1(params, ref, lr * g,
-                                        lr * self.weight_decay,
-                                        self.estimator.dist)
+        with jax.named_scope(phases.UPDATE):
+            if self.batch_seeds > 1:
+                # batched-seed (FZOO) entry: g is the (B,) per-seed vector
+                # and the step was B folded rank-1 applications — replay
+                # them identically
+                from repro.zo.updates import apply_rank1_batch
+                return apply_rank1_batch(params, skey, lr * jnp.asarray(g),
+                                         lr * self.weight_decay,
+                                         dist=self.estimator.dist,
+                                         backend=self.backend,
+                                         selection=sel, phase=phase)
+            ref = StreamRef(skey)
+            if sel is not None:
+                ref = ref.with_selection(sel, phase)
+            return self.backend.apply_rank1(params, ref, lr * g,
+                                            lr * self.weight_decay,
+                                            self.estimator.dist)
 
     def step_fn(self, loss_fn: ZOLossFn) -> Callable[
             [PyTree, ZOState, Any], tuple[PyTree, ZOState, dict]]:
@@ -367,23 +370,21 @@ class ZOOptimizer:
             lr_metric = None
             for j in range(n):
                 skey = jax.random.fold_in(skey0, j) if n > 1 else skey0
-                if n_phases > 1:
-                    e = est.estimate(loss_fn, p, batch, skey, est_state,
-                                     phase=phase)
-                else:
-                    e = est.estimate(loss_fn, p, batch, skey, est_state)
+                e = phases.estimate(est, loss_fn, p, batch, skey, est_state,
+                                    phase if n_phases > 1 else None)
                 est_state = e.est_state
                 ctx = TransformCtx(step=state.step, base_key=state.base_key,
                                    key=skey, seed_index=j, n_seeds=n,
                                    eps=est.eps, dist=est.dist,
                                    restore=e.restore, backend=backend)
-                u = Updates(g=e.projected_grad)
-                u, tf_state = tf.update(u, tf_state, ctx)
-                if u.final_params is not None:
-                    p = u.final_params
-                else:
-                    coeff = u.coeff if u.coeff is not None else u.g
-                    p = e.apply_update(coeff, u.decay)
+                with jax.named_scope(phases.UPDATE):
+                    u, tf_state = tf.update(Updates(g=e.projected_grad),
+                                            tf_state, ctx)
+                    if u.final_params is not None:
+                        p = u.final_params
+                    else:
+                        coeff = u.coeff if u.coeff is not None else u.g
+                        p = e.apply_update(coeff, u.decay)
                 gs.append(u.g)
                 losses.append(e.loss)
                 if e.aux:
