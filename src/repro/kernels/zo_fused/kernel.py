@@ -20,6 +20,11 @@ replay, and the pure-jnp oracle in ref.py — generates bit-identical z.
 
 Grid: 1-D over row-blocks of the (padded) 2-D view; BlockSpec keeps one
 (block_rows × 128·lane_cols) tile of x and y in VMEM (~256 KB at f32).
+Inside a grid step the kernel walks its resident tile in ``STRIP_ROWS``-row
+strips (``_walk_strips``): the z generator on a whole tile is a live set of
+hundreds of vregs, which the compiler spills to VMEM and fills back, while a
+strip's live set fits the 64-vreg register file.  The counter index is the
+element's global position whatever the strip height, so z is the same.
 ``zo_affine_2d_batched`` adds an inner batch grid axis: B z-streams are
 generated against each resident x tile (the ``perturb_many`` entry point for
 batched-seed estimators).  Seeds and affine coefficients are whole 1-D arrays
@@ -37,6 +42,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256
 BLOCK_COLS = 512          # multiple of 128 lanes
+# rows computed at once inside a resident tile: one bf16 (16, 128) tile, two
+# f32 (8, 128) tiles — a strip of z's intermediates stays in registers
+STRIP_ROWS = 16
+# Interpret mode has no register file to fit and takes the tile whole: XLA:CPU
+# then compiles the graphs around the kernels as before the walk, and rounds
+# the losses of a step that inlines them the same way.
+INTERPRET_STRIP_ROWS = BLOCK_ROWS
 
 # whole-array scalar operand (per-stream seeds, coefficients, tile ids)
 SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -203,30 +215,56 @@ def _affine_combine(x: jnp.ndarray, z: jnp.ndarray, a, b,
     return ax + bz
 
 
-def _tile_affine(x: jnp.ndarray, row_block: jnp.ndarray, cols: int,
-                 seed: jnp.ndarray, a, b, interpret: bool,
-                 dist: str = "gaussian") -> jnp.ndarray:
-    """One VMEM tile's worth of y = a·x + b·z(seed): the counter indices are
-    global element positions (row_block picks the tile), so the stream is
-    position-stable across padding and blocking.  Shared by the single-seed
-    and batched kernels — the bitwise batched == singles contract is this
-    function being the only implementation."""
+def _tile_affine(x: jnp.ndarray, offset, cols: int, seed: jnp.ndarray, a, b,
+                 interpret: bool, dist: str = "gaussian") -> jnp.ndarray:
+    """y = a·x + b·z(seed) on a block of rows whose first element sits at
+    global element position ``offset``: the counter index of element
+    (r, c) is ``offset + r·cols + c``, so the stream is position-stable
+    across padding, blocking and strip height.  Shared by every affine
+    kernel — the bitwise batched == singles contract is this function being
+    the only implementation."""
     rows = x.shape[0]
-    base = jnp.uint32(row_block * rows * cols)
     row_ids = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 0)
     col_ids = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1)
-    idx = base + row_ids * jnp.uint32(cols) + col_ids
+    idx = jnp.asarray(offset, jnp.uint32) + row_ids * jnp.uint32(cols) + col_ids
     z = z_from_counter(idx, seed, dist, pin=interpret)
     return _affine_combine(x.astype(jnp.float32), z, a, b, interpret)
 
 
+def _walk_strips(x_ref, o_ref, tile, fn, interpret: bool):
+    """Fill the resident tile ``tile`` of ``o_ref`` strip by strip:
+    ``o[strip] = fn(x[strip], offset)``, where ``offset`` is the global
+    element position of the strip's first element.  A leading axis of
+    ``o_ref`` (the fan-out kernels' stream axis, block size 1) is index 0.
+    Strips are ``STRIP_ROWS`` high when compiled, ``INTERPRET_STRIP_ROWS``
+    under interpret mode."""
+    strip_rows = INTERPRET_STRIP_ROWS if interpret else STRIP_ROWS
+    cols = x_ref.shape[1]
+    lead = (0,) * (len(o_ref.shape) - 2)
+    base = jnp.asarray(tile * (x_ref.shape[0] * cols), jnp.uint32)
+
+    def strip(s, carry):
+        r0 = pl.multiple_of(s * strip_rows, strip_rows)
+        rows = pl.ds(r0, strip_rows)
+        offset = base + jnp.asarray(r0, jnp.uint32) * jnp.uint32(cols)
+        o_ref[lead + (rows, slice(None))] = fn(
+            x_ref[rows, :], offset).astype(o_ref.dtype)
+        return carry
+
+    n_strips = x_ref.shape[0] // strip_rows
+    if n_strips == 1:               # no loop: the tile-as-one-value graph
+        strip(0, 0)
+    else:
+        jax.lax.fori_loop(0, n_strips, strip, 0)
+
+
 def _zo_affine_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *, cols: int,
                       interpret: bool, dist: str):
-    i = pl.program_id(0)
     seed = seed_ref[0].astype(jnp.uint32)
-    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0], b_ref[0],
-                     interpret, dist)
-    o_ref[...] = y.astype(o_ref.dtype)
+    a, b = a_ref[0], b_ref[0]
+    _walk_strips(x_ref, o_ref, pl.program_id(0),
+                 lambda x, off: _tile_affine(x, off, cols, seed, a, b,
+                                             interpret, dist), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "dist"))
@@ -262,11 +300,11 @@ def _zo_affine_batched_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # computation is _tile_affine — the same single implementation the
     # single-seed kernel runs, which is what makes the batched output
     # bitwise-equal to stacked single-seed calls.
-    i = pl.program_id(0)
     seed = seed_ref[pl.program_id(1)].astype(jnp.uint32)
-    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[0], b_ref[0],
-                     interpret, dist)
-    o_ref[0, ...] = y.astype(o_ref.dtype)
+    a, b = a_ref[0], b_ref[0]
+    _walk_strips(x_ref, o_ref, pl.program_id(0),
+                 lambda x, off: _tile_affine(x, off, cols, seed, a, b,
+                                             interpret, dist), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "dist"))

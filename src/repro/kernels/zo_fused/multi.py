@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS, SMEM_SPEC,
-                                           _pin, _tile_affine, z_from_counter)
+                                           _pin, _tile_affine, _walk_strips,
+                                           z_from_counter)
 
 
 # --------------------------------------------------------------------------- #
@@ -56,11 +57,12 @@ def _zo_affine_multi_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # row-block i stays resident while the inner batch axis walks the B
     # (seed_j, a_j, b_j) triples against it.  Same structure as PR 3's
     # batched kernel; the per-stream a/b reads are the generalization.
-    i, j = pl.program_id(0), pl.program_id(1)
+    j = pl.program_id(1)
     seed = seed_ref[j].astype(jnp.uint32)
-    y = _tile_affine(x_ref[...], i, cols, seed, a_ref[j], b_ref[j],
-                     interpret, dist)
-    o_ref[0, ...] = y.astype(o_ref.dtype)
+    a, b = a_ref[j], b_ref[j]
+    _walk_strips(x_ref, o_ref, pl.program_id(0),
+                 lambda x, off: _tile_affine(x, off, cols, seed, a, b,
+                                             interpret, dist), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "dist"))
@@ -104,14 +106,29 @@ def _zo_affine_chain_kernel(x_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # in-register fold must reproduce that rounding boundary to stay bitwise
     # with the per-seed chain.  (The padding tail diverges — the chain keeps
     # b_j·z values there where re-padding would zero them — but padding never
-    # feeds a real element: the ops are elementwise.)
-    i = pl.program_id(0)
-    y = x_ref[...]
-    for j in range(n_streams):
-        seed = seed_ref[j].astype(jnp.uint32)
-        y = _tile_affine(y, i, cols, seed, a_ref[j], b_ref[j],
-                         interpret, dist).astype(x_ref.dtype)
-    o_ref[...] = y
+    # feeds a real element: the ops are elementwise.)  The folds run inside
+    # each strip, so a strip's y stays in registers between streams.
+    _walk_strips(x_ref, o_ref, pl.program_id(0),
+                 _chain_fold(seed_ref, a_ref, b_ref, n_streams, cols,
+                             x_ref.dtype, interpret, dist), interpret)
+
+
+def _chain_fold(seed_ref, a_ref, b_ref, n_streams: int, cols: int, dtype,
+                interpret: bool, dist: str):
+    """``fold(y, offset)``: the n_streams affine folds of one strip, each
+    rounded to ``dtype`` as a separate single-seed launch would write it.
+    Shared by the full and the selected-tiles chain kernels."""
+    seeds = [seed_ref[j].astype(jnp.uint32) for j in range(n_streams)]
+    a = [a_ref[j] for j in range(n_streams)]
+    b = [b_ref[j] for j in range(n_streams)]
+
+    def fold(y, offset):
+        for j in range(n_streams):
+            y = _tile_affine(y, offset, cols, seeds[j], a[j], b[j],
+                             interpret, dist).astype(dtype)
+        return y
+
+    return fold
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "dist"))

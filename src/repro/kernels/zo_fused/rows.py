@@ -45,7 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.zo_fused.kernel import (BLOCK_COLS, BLOCK_ROWS, SMEM_SPEC,
-                                           _pin, _tile_affine, z_from_counter)
+                                           _pin, _tile_affine, _walk_strips,
+                                           z_from_counter)
+from repro.kernels.zo_fused.multi import _chain_fold
 
 TILE_ELEMS = BLOCK_ROWS * BLOCK_COLS
 
@@ -84,17 +86,33 @@ def tile_plan(n: int, block_elems: int, k: int, phase: int) -> tuple:
     return tuple(sel), pure
 
 
-def _tile_sel_mask(row_block, cols: int, block_elems: int, k: int,
+def _tile_sel_mask(offset, shape: tuple, block_elems: int, k: int,
                    phase: int) -> jnp.ndarray:
-    """Selected-element predicate of one tile, from the same global counter
+    """Selected-element predicate of a ``shape`` block of rows whose first
+    element sits at global position ``offset``, from the same counter
     indices ``_tile_affine`` generates z with: element e is in row-block
     ``e // block_elems``, selected iff ``≡ phase (mod k)``."""
-    base = jnp.uint32(row_block * BLOCK_ROWS * cols)
-    row_ids = jax.lax.broadcasted_iota(jnp.uint32, (BLOCK_ROWS, cols), 0)
-    col_ids = jax.lax.broadcasted_iota(jnp.uint32, (BLOCK_ROWS, cols), 1)
-    idx = base + row_ids * jnp.uint32(cols) + col_ids
+    row_ids = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+    col_ids = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
+    idx = (jnp.asarray(offset, jnp.uint32) + row_ids * jnp.uint32(shape[1])
+           + col_ids)
     blk = idx // jnp.uint32(block_elems)
     return (blk % jnp.uint32(k)) == jnp.uint32(phase)
+
+
+def _selected(fn, dtype, block_elems: int, k: int, phase: int,
+              masked: bool):
+    """Wrap a strip function ``fn(x, offset)`` so that its result is cast to
+    ``dtype`` and, when ``masked``, keeps x's own bits at the strip's
+    unselected elements (the select follows the cast, so they are exactly
+    x)."""
+    def strip(x, offset):
+        y = fn(x, offset).astype(dtype)
+        if masked:
+            y = jnp.where(_tile_sel_mask(offset, x.shape, block_elems, k,
+                                         phase), y, x)
+        return y
+    return strip
 
 
 def _gather_tiles(x: jnp.ndarray, sel: tuple) -> jnp.ndarray:
@@ -131,14 +149,12 @@ def _zo_affine_rows_kernel(x_ref, tile_ref, seed_ref, a_ref, b_ref, o_ref, *,
     # the grid walks the COMPACT tile axis; the original tile index arrives
     # as data, so _tile_affine's global counter base — and therefore the z
     # bits — match the full-grid kernel exactly
-    t = tile_ref[pl.program_id(0)]
     seed = seed_ref[0].astype(jnp.uint32)
-    x = x_ref[...]
-    y = _tile_affine(x, t, cols, seed, a_ref[0], b_ref[0],
-                     interpret, dist).astype(o_ref.dtype)
-    if masked:
-        y = jnp.where(_tile_sel_mask(t, cols, block_elems, k, phase), y, x)
-    o_ref[...] = y
+    a, b = a_ref[0], b_ref[0]
+    _walk_strips(x_ref, o_ref, tile_ref[pl.program_id(0)], _selected(
+        lambda x, off: _tile_affine(x, off, cols, seed, a, b, interpret,
+                                    dist),
+        o_ref.dtype, block_elems, k, phase, masked), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("sel", "block_elems", "k",
@@ -188,15 +204,13 @@ def _zo_affine_multi_rows_kernel(x_ref, tile_ref, seed_ref, a_ref, b_ref,
     # grid (n_sel, batch): compact tile axis OUTER so the x tile stays
     # resident while the inner batch axis walks the B streams against it —
     # the multi.py structure over the compact operand
-    t = tile_ref[pl.program_id(0)]
     j = pl.program_id(1)
     seed = seed_ref[j].astype(jnp.uint32)
-    x = x_ref[...]
-    y = _tile_affine(x, t, cols, seed, a_ref[j], b_ref[j],
-                     interpret, dist).astype(o_ref.dtype)
-    if masked:
-        y = jnp.where(_tile_sel_mask(t, cols, block_elems, k, phase), y, x)
-    o_ref[0, ...] = y
+    a, b = a_ref[j], b_ref[j]
+    _walk_strips(x_ref, o_ref, tile_ref[pl.program_id(0)], _selected(
+        lambda x, off: _tile_affine(x, off, cols, seed, a, b, interpret,
+                                    dist),
+        o_ref.dtype, block_elems, k, phase, masked), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("sel", "block_elems", "k",
@@ -247,20 +261,15 @@ def _zo_affine_chain_rows_kernel(x_ref, tile_ref, seed_ref, a_ref, b_ref,
                                  o_ref, *, cols: int, n_streams: int,
                                  block_elems: int, k: int, phase: int,
                                  masked: bool, interpret: bool, dist: str):
-    # the fold runs on the whole tile (every op is elementwise, so selected
+    # the fold runs on the whole strip (every op is elementwise, so selected
     # elements' values never depend on unselected neighbours) and the block
     # predicate restores x's bits once at the end — equivalent to masking
     # every fold step, at one select instead of n_streams
-    t = tile_ref[pl.program_id(0)]
-    x = x_ref[...]
-    y = x
-    for j in range(n_streams):
-        seed = seed_ref[j].astype(jnp.uint32)
-        y = _tile_affine(y, t, cols, seed, a_ref[j], b_ref[j],
-                         interpret, dist).astype(x_ref.dtype)
-    if masked:
-        y = jnp.where(_tile_sel_mask(t, cols, block_elems, k, phase), y, x)
-    o_ref[...] = y
+    fold = _chain_fold(seed_ref, a_ref, b_ref, n_streams, cols, x_ref.dtype,
+                       interpret, dist)
+    _walk_strips(x_ref, o_ref, tile_ref[pl.program_id(0)],
+                 _selected(fold, o_ref.dtype, block_elems, k, phase, masked),
+                 interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("sel", "block_elems", "k",

@@ -22,13 +22,17 @@ excluded):
   * engine: ``apply_group_updates`` (the flattened one-call write path)
     ≡ the per-group ``apply_group_update`` fold.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.zo_fused import kernel as zo_kernel
 from repro.kernels.zo_fused import multi as zo_multi
 from repro.kernels.zo_fused import ref as zo_ref
+from repro.kernels.zo_fused import rows as zo_rows
 from repro.perturb import StreamRef, get_backend
 from repro.perturb import pallas as pallas_mod
 
@@ -251,3 +255,113 @@ def test_apply_group_updates_bitwise_vs_per_group_fold(backend, batch_seeds):
                                  0.001 if g == 0 else 0.0, batch_seeds,
                                  "gaussian", be)
     tree_eq(fused, seq)
+
+
+# --------------------------------------------------------------------------- #
+# Strip walk: every affine kernel computes its resident tile strip by strip
+# --------------------------------------------------------------------------- #
+STRIP_SEEDS = jnp.asarray([3, 16, 29], jnp.int32)
+STRIP_A = jnp.asarray([0.999, 1.0, 0.95], jnp.float32)
+STRIP_B = jnp.asarray([-0.02, 0.01, 0.005], jnp.float32)
+# rows(block=100_000, k=2, phase=1) over the 3 tiles of a 280_000-element
+# leaf: tiles 0 and 2 straddle selected and unselected blocks (masked)
+STRIP_SEL = dict(block_elems=100_000, k=2, phase=1, sel=(0, 2), masked=True)
+# block == tile, k=3, phase=2: tile 2 alone, purely selected (no mask)
+STRIP_SEL_PURE = dict(block_elems=zo_kernel.BLOCK_ROWS * zo_kernel.BLOCK_COLS,
+                      k=3, phase=2, sel=(2,), masked=False)
+
+
+@functools.partial(jax.jit, static_argnames=("n_streams", "block_elems",
+                                             "k", "phase", "masked"))
+def _whole_tile_oracle(tile, offset, seeds, a, b, n_streams, block_elems=1,
+                       k=1, phase=0, masked=False):
+    """One WHOLE (BLOCK_ROWS, cols) tile at global ``offset``: the first
+    ``n_streams`` affine folds of ``_tile_affine`` in the tile's dtype, x's
+    bits kept at unselected elements when ``masked`` — the per-tile
+    arithmetic as it was before the strip walk."""
+    y = tile
+    for j in range(n_streams):
+        y = zo_kernel._tile_affine(y, offset, tile.shape[1],
+                                   seeds[j].astype(jnp.uint32), a[j], b[j],
+                                   True).astype(tile.dtype)
+    if masked:
+        keep = zo_rows._tile_sel_mask(offset, tile.shape, block_elems, k,
+                                      phase)
+        y = jnp.where(keep, y, tile)
+    return y
+
+
+def _oracle(x2d, seeds, a, b, n_streams=1, sel=None):
+    """The oracle over the blocked view: every tile, or (``sel``) the
+    selected tiles with x's own rows elsewhere."""
+    r, cols = zo_kernel.BLOCK_ROWS, x2d.shape[1]
+    out = np.array(x2d)
+    opts = {} if sel is None else {k: v for k, v in sel.items() if k != "sel"}
+    for t in (range(x2d.shape[0] // r) if sel is None else sel["sel"]):
+        out[t * r:(t + 1) * r] = np.asarray(_whole_tile_oracle(
+            x2d[t * r:(t + 1) * r], jnp.uint32(t * r * cols), seeds, a, b,
+            n_streams, **opts))
+    return out
+
+
+def _strip_case(kind, x2d):
+    """(kernel output, whole-tile oracle) for one strip-walked kernel."""
+    S, A, B = STRIP_SEEDS, STRIP_A, STRIP_B
+    one = lambda j: (S[j:j + 1], A[j:j + 1], B[j:j + 1])
+    if kind in ("single", "strips_8", "strips_16"):
+        return (zo_kernel.zo_affine_2d(x2d, S[0], A[0], B[0]),
+                _oracle(x2d, *one(0)))
+    if kind == "batched":
+        return (zo_kernel.zo_affine_2d_batched(x2d, S, A[0], B[0]),
+                np.stack([_oracle(x2d, S[j:j + 1], A[:1], B[:1])
+                          for j in range(3)]))
+    if kind == "multi":
+        return (zo_multi.zo_affine_multi_2d(x2d, S, A, B),
+                np.stack([_oracle(x2d, *one(j)) for j in range(3)]))
+    if kind == "chain":
+        return (zo_multi.zo_affine_chain_2d(x2d, S, A, B),
+                _oracle(x2d, S, A, B, n_streams=3))
+    if kind.startswith("rows"):
+        sel = STRIP_SEL_PURE if kind.endswith("pure") else STRIP_SEL
+        if kind.startswith("rows_multi"):
+            return (zo_rows.zo_affine_multi_2d_rows(x2d, S, A, B, **sel),
+                    np.stack([_oracle(x2d, *one(j), sel=sel)
+                              for j in range(3)]))
+        if kind.startswith("rows_chain"):
+            return (zo_rows.zo_affine_chain_2d_rows(x2d, S, A, B, **sel),
+                    _oracle(x2d, S, A, B, n_streams=3, sel=sel))
+        return (zo_rows.zo_affine_2d_rows(x2d, S[0], A[0], B[0], **sel),
+                _oracle(x2d, *one(0), sel=sel))
+    raise ValueError(kind)
+
+
+@pytest.fixture
+def fresh_traces():
+    """Every kernel traces anew (its strip height is read at trace time)."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kind", [
+    "single", "batched", "multi", "chain", "rows", "rows_pure",
+    "rows_multi", "rows_chain", "strips_8", "strips_16"])
+def test_strip_walk_bitwise_vs_whole_tile(kind, dtype, monkeypatch,
+                                          fresh_traces):
+    """Each affine kernel, walking its resident tile in the compiled
+    kernels' ``STRIP_ROWS`` strips under interpret mode too, gives on a leaf
+    of three tiles with a padded tail, bit for bit, ``_tile_affine`` applied
+    to each whole tile at the same global offsets (the selected-tiles
+    kernels: on the selected tiles, x elsewhere).  The ``strips_<h>`` cases
+    run the single-seed kernel at heights 8 and 16: z at an element does not
+    depend on the strip height."""
+    height = int(kind[7:]) if kind.startswith("strips_") \
+        else zo_kernel.STRIP_ROWS
+    monkeypatch.setattr(zo_kernel, "INTERPRET_STRIP_ROWS", height)
+    x = leaf(dtype, (700, 400))                       # 280_000 elements
+    x2d, n = pallas_mod._blocked_view(x)
+    assert x2d.shape[0] == 3 * zo_kernel.BLOCK_ROWS and n < x2d.size
+    got, want = _strip_case(kind, x2d)
+    np.testing.assert_array_equal(np.asarray(got), want)

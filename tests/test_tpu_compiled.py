@@ -19,6 +19,8 @@ kernel-vs-oracle equalities are reported expectations; if a Mosaic release
 moves them, the right response is a pallas stream-id bump (see
 ``perturb.base``), not a silent tolerance widen.
 """
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,6 +35,7 @@ pytestmark = [
 ]
 
 from repro.kernels.zo_fused import multi as zo_multi            # noqa: E402
+from repro.kernels.zo_fused.kernel import zo_affine_2d          # noqa: E402
 from repro.kernels.zo_fused import ref as zo_ref                # noqa: E402
 from repro.perturb import StreamRef, get_backend                # noqa: E402
 from repro.perturb import pallas as pallas_mod                  # noqa: E402
@@ -101,6 +104,35 @@ def test_compiled_sphere_backend_roundtrip():
                     jax.tree_util.tree_leaves(params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=0)
+
+
+# sha256 of the compiled kernel's output bytes on a TPU v5e ("TPU v5 lite"),
+# recorded from the kernel as it was before the strip walk (whole tile as
+# one value): the walk changed the schedule, not one bit of y.
+COMPILED_DIGESTS = {
+    "bf16_4096x512": "012d2f8b3b614375189653d9878515692b7d3c1fc171fbe59499db1f36ecafbd",
+    "f32_300x40": "09e4845ea4beef3cfe4f4ab54d4852f7ca81cab60eb44a282ca92399f42f9983",
+}
+
+
+def _fixed_x(shape, dtype):
+    """An input every platform makes with the same bits: integers / 256."""
+    i = jnp.arange(int(np.prod(shape)), dtype=jnp.int32)
+    x = ((i * 7919) % 4001 - 2000).astype(jnp.float32) / 256.0
+    return x.astype(dtype).reshape(shape)
+
+
+@pytest.mark.parametrize("case", sorted(COMPILED_DIGESTS))
+def test_compiled_affine_bits_match_recorded_digest(case):
+    if case == "bf16_4096x512":
+        y = zo_affine_2d(_fixed_x((4096, 512), jnp.bfloat16),
+                         jnp.int32(12345), jnp.float32(0.999),
+                         jnp.float32(0.05), interpret=False)
+    else:
+        y = pallas_mod.zo_affine(_fixed_x((300, 40), jnp.float32), 12345,
+                                 0.999, 0.05, interpret=False)
+    got = hashlib.sha256(np.asarray(y).tobytes()).hexdigest()
+    assert got == COMPILED_DIGESTS[case]
 
 
 # --------------------------------------------------------------------------- #
