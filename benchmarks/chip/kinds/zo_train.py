@@ -31,7 +31,7 @@ import time
 import jax
 import numpy as np
 
-from benchmarks.chip import flops
+from benchmarks.chip import flops, phases
 from benchmarks.chip import weights as wgen
 from benchmarks.chip.reference import mezo as ref_mezo
 
@@ -206,6 +206,15 @@ def run(ctx) -> dict:
                 break
     window_s = elapsed
     peaks["window"] = ctx.window_peak
+    info = {"steps": n, "window_s": window_s, "step_ms": 1e3 * window_s / n,
+            "peak_bytes": peaks}
+    traced = {}
+    if ctx.trace:
+        # the scope of each device op, for the readers of program spans
+        t0 = time.perf_counter()
+        traced["op_names"] = phases.hlo_op_names(job.compiled.as_text())
+        info["op_names_s"] = time.perf_counter() - t0
+        traced["traffic"] = tr
     job.params = job.state = job.step = job.compiled = None
     ctx.free()
 
@@ -219,8 +228,7 @@ def run(ctx) -> dict:
             "train_tokens_per_s": ("tokens/s", n * tokens_per_step / window_s),
             "peak_hbm_gb": ("GB", ctx.window_peak / 1e9),
         },
-        "info": {"steps": n, "window_s": window_s,
-                 "step_ms": 1e3 * window_s / n, "peak_bytes": peaks},
+        "info": info,
         "layer_ctx": {
             "job": "zo_train", "steps": n, "window_s": window_s,
             "model": model,
@@ -229,6 +237,7 @@ def run(ctx) -> dict:
             "kernel_bytes_per_step": flops.zo_kernel_bytes_per_step(model),
             "kernel_needles": KERNEL_NEEDLES,
             "temp_bytes": memory.temp_size_in_bytes,
+            **traced,
         },
     }
     return out

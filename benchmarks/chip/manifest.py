@@ -5,12 +5,36 @@
                                    driver in ``kinds/<kind>.py``
     limits/<cell>.json             the limits of a cell's correctness check
     metrics/<metric>.py            a per-layer metric's reader, ``read(run)``
+    reference/<family>.py          a model family, named by a configuration's
+                                   ``model["family"]``
 
-A later cell, mix or metric is a new file and a new entry; no file here
-changes.
+A family module gives:
+
+    leaf_specs(model)              ``path -> (shape, kind, std)`` of every
+                                   leaf of the program's parameter tree
+    loss(model, get, get_rows, tokens, labels, precision)
+                                   the float32 reference loss
+    logits(model, get, get_rows, tokens)
+                                   float32 logits, for the CPU agreement tests
+    forward_flops(model, batch, seq)
+                                   model FLOPs of one forward
+    CPU_CASES                      ``{id: model}``, small models that the CPU
+                                   tests run against the program
+
+The family owns its whole tree: leading dense layers, attention of several
+kinds, an expert axis are its own business.  The reference asks for a leaf
+through ``get(path, layer)``, in float32.  A leaf whose first axis is the
+layer is asked for one layer at a time, and its z then starts at flat
+offset ``layer · prod(shape[1:])``; any other leaf is asked for whole
+(``layer`` None).  The embedding is the leaf ``embed``, asked for by rows,
+``get_rows(ids)``.
+
+A later cell, mix, metric or family is a new file and a new entry; no file
+here changes.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -63,3 +87,36 @@ def reader(metric_name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+FAMILY_API = ("leaf_specs", "loss", "logits", "forward_flops", "CPU_CASES")
+
+
+def _family_module(name: str):
+    """``reference/<name>.py`` if it gives the family API, else None."""
+    if not name.isidentifier():
+        return None
+    full = f"{__package__}.reference.{name}"
+    try:
+        mod = importlib.import_module(full)
+    except ModuleNotFoundError as e:
+        if e.name != full:
+            raise
+        return None
+    return mod if all(hasattr(mod, a) for a in FAMILY_API) else None
+
+
+def families() -> list:
+    """The names of the family modules under ``reference/``."""
+    return [p.stem for p in sorted((HERE / "reference").glob("*.py"))
+            if not p.stem.startswith("_")
+            and _family_module(p.stem) is not None]
+
+
+def family(name: str):
+    """The module of model family ``name``: ``reference/<name>.py``."""
+    mod = _family_module(name)
+    if mod is None:
+        raise KeyError(f"no model family {name!r}: benchmarks/chip/reference/"
+                       f" has the families {families()}")
+    return mod

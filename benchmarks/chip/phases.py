@@ -11,7 +11,9 @@ sum: a ``%while`` op spans the ops of its body, which appear too.
 The op names come from the compiled step's HLO text (:func:`hlo_op_names`),
 keyed by the instruction name that the v5e trace gives each device op
 (``%zo_affine_2d.52 = bf16[...] custom-call(...)``, :func:`instruction`).
-A program without the scopes gives no op a phase, and every phase time 0.
+A traced run of the training job hands that map to the metric readers as
+``run["op_names"]``, taken after the window.  A program without the scopes
+gives no op a phase, and every phase time 0.
 The names are the program's (``repro.zo.phases``), spelled here so that the
 reduction also runs on a program that lacks them.
 """
@@ -28,13 +30,13 @@ _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?\bop_name="([^"]*)"',
                     re.M)
 
 
-def phase_of(op_name: str):
-    """The innermost phase named in an ``op_name`` path, also where a
-    transform wraps it (``vmap(zo_forward)``), else ``None``."""
+def phase_of(op_name: str, scopes=PHASES):
+    """The innermost of ``scopes`` named in an ``op_name`` path, also where
+    a transform wraps it (``vmap(zo_forward)``), else ``None``."""
     for part in reversed(op_name.split("/")):
         while part.endswith(")") and "(" in part:    # vmap(zo_forward)
             part = part[part.index("(") + 1:-1]
-        if part in PHASES:
+        if part in scopes:
             return part
     return None
 
@@ -51,9 +53,11 @@ def instruction(op_name_in_trace: str) -> str:
     return op_name_in_trace.split(" = ", 1)[0].strip().lstrip("%")
 
 
-def op_phases(ops, op_names: dict) -> list:
-    """The phase of each device op ``[name, start_ns, dur_ns]``."""
-    return [phase_of(op_names.get(instruction(o[0]), "")) for o in ops]
+def op_phases(ops, op_names: dict, scopes=PHASES) -> list:
+    """The innermost of ``scopes`` that names each device op ``[name,
+    start_ns, dur_ns]``, or ``None``."""
+    return [phase_of(op_names.get(instruction(o[0]), ""), scopes)
+            for o in ops]
 
 
 def phase_ns(ops, op_phase, window, phases) -> int:
@@ -61,3 +65,22 @@ def phase_ns(ops, op_phase, window, phases) -> int:
     those ops' intervals."""
     return trace_reduce.busy_ns(
         [o for o, ph in zip(ops, op_phase) if ph in phases], window)
+
+
+def scope_ns_per_step(run: dict, scopes) -> float | None:
+    """Device time a step of a traced run under ``scopes`` (ns): the union
+    of the window's device ops whose innermost named scope is one of
+    ``scopes``, over ``run["steps"]``.  Innermost among the three phases and
+    ``scopes``, so that a scope inside ``zo_forward`` takes its ops from the
+    forward, and ``zo_forward`` inside ``zo_perturb`` from the perturbation.
+    ``None`` where the run has no op names (``run["op_names"]``, traced runs
+    only) or no op falls under ``scopes`` inside the window."""
+    op_names, steps = run.get("op_names"), run.get("steps")
+    if not op_names or not steps:
+        return None
+    scopes = tuple(scopes)
+    among = PHASES + tuple(s for s in scopes if s not in PHASES)
+    ops = run["trace"]["device_ops"]
+    ns = phase_ns(ops, op_phases(ops, op_names, among), run["trace"]["window"],
+                  scopes)
+    return ns / steps if ns else None

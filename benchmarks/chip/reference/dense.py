@@ -1,4 +1,7 @@
-"""Plain float32 forward of the dense decoder the configurations describe.
+"""The ``dense`` family: the decoder of the configurations whose ``model``
+says ``"family": "dense"`` — its parameter layout, its plain float32
+forward and loss, and its model FLOPs (the family API of
+:mod:`benchmarks.chip.manifest`).
 
 Straightforward ``jax.numpy`` under ``precision="highest"``: no kernels, no
 cache, no batching tricks, one layer at a time so that the reference fits
@@ -30,7 +33,96 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from benchmarks.chip.weights import padded_vocab
+
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# Small models that the CPU tests run against the program: an OPT-like and
+# a Qwen2-like block (grouped kv heads, gated FFN, RMSNorm, q/k/v biases).
+CPU_CASES = {
+    "opt-like": {"name": "opt-tiny", "family": "dense", "n_layers": 2,
+                 "d_model": 64, "n_heads": 4, "n_kv_heads": 4, "d_ff": 256,
+                 "vocab_size": 300, "activation": "relu", "gated_ffn": False,
+                 "norm": "layernorm", "qkv_bias": False,
+                 "rope_theta": 10000.0, "max_seq": 64, "dtype": "float32"},
+    "qwen2-like": {"name": "qwen-tiny", "family": "dense", "n_layers": 2,
+                   "d_model": 64, "n_heads": 4, "n_kv_heads": 2, "d_ff": 128,
+                   "vocab_size": 300, "activation": "silu", "gated_ffn": True,
+                   "norm": "rmsnorm", "qkv_bias": True, "rope_theta": 1e6,
+                   "max_seq": 64, "dtype": "float32"},
+}
+
+
+def leaf_specs(model: dict) -> dict:
+    """``path -> (shape, kind, std)`` of every leaf.  ``kind`` is ``normal``
+    (std given), ``ones`` or ``zeros``.  Layers stacked on axis 0, the
+    layout the program's dense forward takes:
+
+        embed (Vp, d) · head (d, Vp) · ln_f
+        layers: ln1, ln2 {scale[, bias]} · attn {wq, wk, wv, wo[, bq, bk, bv]}
+                · mlp {w1, w2[, w3]}
+
+    Vp is the vocabulary padded to a multiple of 128."""
+    d, L, ff = model["d_model"], model["n_layers"], model["d_ff"]
+    H = model["n_heads"]
+    kv = model.get("n_kv_heads") or H
+    hd = d // H
+    V = padded_vocab(model)
+    rms = model.get("norm", "layernorm") == "rmsnorm"
+
+    def norm(prefix, lead=()):
+        out = {f"{prefix}/scale": (lead + (d,), "zeros" if rms else "ones", 0.0)}
+        if not rms:
+            out[f"{prefix}/bias"] = (lead + (d,), "zeros", 0.0)
+        return out
+
+    s = {"embed": ((V, d), "normal", 0.02),
+         "head": ((d, V), "normal", d ** -0.5)}
+    s.update(norm("ln_f"))
+    s.update(norm("layers/ln1", (L,)))
+    s.update(norm("layers/ln2", (L,)))
+    s["layers/attn/wq"] = ((L, d, H * hd), "normal", d ** -0.5)
+    s["layers/attn/wk"] = ((L, d, kv * hd), "normal", d ** -0.5)
+    s["layers/attn/wv"] = ((L, d, kv * hd), "normal", d ** -0.5)
+    s["layers/attn/wo"] = ((L, H * hd, d), "normal", (H * hd) ** -0.5)
+    if model.get("qkv_bias"):
+        s["layers/attn/bq"] = ((L, H * hd), "normal", 0.02)
+        s["layers/attn/bk"] = ((L, kv * hd), "normal", 0.02)
+        s["layers/attn/bv"] = ((L, kv * hd), "normal", 0.02)
+    s["layers/mlp/w1"] = ((L, d, ff), "normal", d ** -0.5)
+    s["layers/mlp/w2"] = ((L, ff, d), "normal", ff ** -0.5)
+    if model.get("gated_ffn"):
+        s["layers/mlp/w3"] = ((L, d, ff), "normal", d ** -0.5)
+    return s
+
+
+def matmul_params(model: dict) -> int:
+    """Every leaf but the embedding (a gather, not a matmul); the head
+    counts the true vocabulary only."""
+    n = 0
+    for path, (shape, _, _) in leaf_specs(model).items():
+        if path == "embed":
+            continue
+        size = 1
+        for dim in shape:
+            size *= dim
+        n += size
+    return n - model["d_model"] * (padded_vocab(model) - model["vocab_size"])
+
+
+def attention_flops(model: dict, batch: int, q_len: int, kv_len: int) -> int:
+    hd = model["d_model"] // model["n_heads"]
+    return 2 * 2 * model["n_layers"] * batch * q_len * kv_len \
+        * model["n_heads"] * hd
+
+
+def forward_flops(model: dict, batch: int, seq: int) -> int:
+    """One forward over ``batch`` rows of ``seq`` tokens: 2·N per token for
+    the N matmul parameters plus 4·S_q·S_kv·H·hd per layer and row for
+    attention, scores and values, over the whole square as the program
+    computes it.  A copy of the program's ``analysis/flops.py``."""
+    return 2 * matmul_params(model) * batch * seq \
+        + attention_flops(model, batch, seq, seq)
 
 
 def _q8(x):

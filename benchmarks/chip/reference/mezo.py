@@ -1,4 +1,5 @@
-"""Plain MeZO (the paper's Algorithm 1) over the float32 reference forward.
+"""Plain MeZO (the paper's Algorithm 1) over the float32 reference loss of
+the model's family (``reference/<family>.py``).
 
 The parameters are stored in the dtype the configuration states and are
 perturbed in place, as Algorithm 1 does: each of the three writes of a step
@@ -24,8 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.chip import manifest
 from benchmarks.chip import weights as wgen
-from benchmarks.chip.reference import dense, zgen
+from benchmarks.chip.reference import zgen
 
 
 def _store(x, dtype):
@@ -94,6 +96,7 @@ def steps(model: dict, weights_seed: int, zo_seed: int, batches: list,
 
     Returns per-step ``losses`` (mean of the two), per-step projected
     gradients ``g``, and ``change``: per leaf, ‖θ_T − θ_0‖ in float32."""
+    loss = manifest.family(model["family"]).loss
     specs = wgen.leaf_specs(model)
     tree = wgen._nest({p: 0 for p in specs})
     paths = wgen.flat_paths(tree)
@@ -105,10 +108,10 @@ def steps(model: dict, weights_seed: int, zo_seed: int, batches: list,
             tokens, labels = tokens[: len(tokens) // 2], labels[: len(labels) // 2]
         st = zgen.step_seed(zo_seed, t)
         seeds = {p: zgen.leaf_seed(st, i) for i, p in enumerate(paths)}
-        lp = dense.loss(model, *_source(theta, seeds, eps32, "+"), tokens,
-                        labels, precision)
-        lm = dense.loss(model, *_source(theta, seeds, eps32, "-"), tokens,
-                        labels, precision)
+        lp = loss(model, *_source(theta, seeds, eps32, "+"), tokens, labels,
+                  precision)
+        lm = loss(model, *_source(theta, seeds, eps32, "-"), tokens, labels,
+                  precision)
         g = (np.float32(lp) - np.float32(lm)) / np.float32(2.0 * eps)
         if fault == "neg_grad":
             g = -g
@@ -117,10 +120,10 @@ def steps(model: dict, weights_seed: int, zo_seed: int, batches: list,
             bad = labels.copy()
             bad[0, 0] = (bad[0, 0] + 1) % model["vocab_size"]
             reported = 0.5 * (
-                dense.loss(model, *_source(theta, seeds, eps32, "+"), tokens,
-                           bad, precision)
-                + dense.loss(model, *_source(theta, seeds, eps32, "-"),
-                             tokens, bad, precision))
+                loss(model, *_source(theta, seeds, eps32, "+"), tokens, bad,
+                     precision)
+                + loss(model, *_source(theta, seeds, eps32, "-"), tokens, bad,
+                       precision))
         losses.append(float(reported))
         gs.append(float(g))
         if fault != "frozen":

@@ -1,18 +1,14 @@
-"""Random weights for a dense configuration, made from the run's seed.
+"""Random weights for a configuration, made from the run's seed.
 
 The benchmark owns the weights: it makes them on the device in one jitted
 call, in the dtype the configuration serves, and hands them to the program.
 The reference makes the same bits again from the same seed, leaf by leaf, so
 it never reads a weight the program has held.
 
-Layout (layers stacked on axis 0), the one the program's dense forward takes:
-
-    embed (Vp, d) · head (d, Vp) · ln_f
-    layers: ln1, ln2 {scale[, bias]} · attn {wq, wk, wv, wo[, bq, bk, bv]}
-            · mlp {w1, w2[, w3]}
-
-Vp is the vocabulary padded to a multiple of 128; the padded rows and
-columns are drawn like the rest and never reach a loss or a token.
+The layout is the model family's (``reference/<family>.py``'s
+``leaf_specs``); each leaf is drawn from the seed and its path alone.  The
+vocabulary is padded to a multiple of 128; the padded rows and columns are
+drawn like the rest and never reach a loss or a token.
 """
 from __future__ import annotations
 
@@ -22,6 +18,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmarks.chip import manifest
 
 
 def seeds(seed: int) -> dict:
@@ -36,39 +34,10 @@ def padded_vocab(model: dict) -> int:
 
 
 def leaf_specs(model: dict) -> dict:
-    """``path -> (shape, kind, std)`` of every leaf.  ``kind`` is ``normal``
-    (std given), ``ones`` or ``zeros``."""
-    d, L, ff = model["d_model"], model["n_layers"], model["d_ff"]
-    H = model["n_heads"]
-    kv = model.get("n_kv_heads") or H
-    hd = d // H
-    V = padded_vocab(model)
-    rms = model.get("norm", "layernorm") == "rmsnorm"
-
-    def norm(prefix, lead=()):
-        out = {f"{prefix}/scale": (lead + (d,), "zeros" if rms else "ones", 0.0)}
-        if not rms:
-            out[f"{prefix}/bias"] = (lead + (d,), "zeros", 0.0)
-        return out
-
-    s = {"embed": ((V, d), "normal", 0.02),
-         "head": ((d, V), "normal", d ** -0.5)}
-    s.update(norm("ln_f"))
-    s.update(norm("layers/ln1", (L,)))
-    s.update(norm("layers/ln2", (L,)))
-    s["layers/attn/wq"] = ((L, d, H * hd), "normal", d ** -0.5)
-    s["layers/attn/wk"] = ((L, d, kv * hd), "normal", d ** -0.5)
-    s["layers/attn/wv"] = ((L, d, kv * hd), "normal", d ** -0.5)
-    s["layers/attn/wo"] = ((L, H * hd, d), "normal", (H * hd) ** -0.5)
-    if model.get("qkv_bias"):
-        s["layers/attn/bq"] = ((L, H * hd), "normal", 0.02)
-        s["layers/attn/bk"] = ((L, kv * hd), "normal", 0.02)
-        s["layers/attn/bv"] = ((L, kv * hd), "normal", 0.02)
-    s["layers/mlp/w1"] = ((L, d, ff), "normal", d ** -0.5)
-    s["layers/mlp/w2"] = ((L, ff, d), "normal", ff ** -0.5)
-    if model.get("gated_ffn"):
-        s["layers/mlp/w3"] = ((L, d, ff), "normal", d ** -0.5)
-    return s
+    """``path -> (shape, kind, std)`` of every leaf, as the model's family
+    module lays its tree out.  ``kind`` is ``normal`` (std given), ``ones``
+    or ``zeros``."""
+    return manifest.family(model["family"]).leaf_specs(model)
 
 
 def _leaf(key, path: str, shape, kind: str, std: float, dtype):

@@ -124,3 +124,27 @@ def test_control_fails_the_limits():
                                zo_train.sizes(MODEL))
     limits = _limits()
     assert any(numbers[k] > limits[k] for k in limits), (numbers, limits)
+
+
+def test_only_a_traced_run_hands_readers_op_names_and_traffic():
+    """The compiled step's op names (for the readers of program spans) and
+    the traffic reach the readers in a traced run, taken after the window;
+    an untraced run does not read the compiled text at all."""
+    from benchmarks.chip import phases
+    cell = manifest.cell(manifest.load(), CELL)
+    for trace in (False, True):
+        ctx = run.Ctx(cell, {"model": MODEL}, _traffic(), SEED, 0.3, trace,
+                      jax.devices()[:1], span_names=zo_train.SPAN_NAMES)
+        try:
+            out = zo_train.run(ctx)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(ctx._on_event)
+        lc = out["layer_ctx"]
+        if not trace:
+            assert "op_names" not in lc and "traffic" not in lc
+            assert "op_names_s" not in out["info"]
+            continue
+        assert lc["traffic"] == _traffic()
+        assert out["info"]["op_names_s"] >= 0
+        named = {phases.phase_of(v) for v in lc["op_names"].values()}
+        assert set(phases.PHASES) <= named

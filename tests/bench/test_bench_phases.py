@@ -8,6 +8,7 @@ from pathlib import Path
 
 import pytest
 
+from benchmarks.chip import flops, manifest, peaks
 from benchmarks.chip import phases as ph
 from benchmarks.chip import trace_reduce as tr
 from benchmarks.chip.kinds import zo_train
@@ -76,6 +77,24 @@ def test_phase_time_is_a_union_clipped_to_the_window(op_names):
         == 10 + 50
 
 
+def test_a_scope_takes_its_ops_from_the_phase_around_it(op_names):
+    """``scope_ns_per_step``: a phase keeps the ops of a phase nested in
+    it out, a new scope inside a phase takes its ops, and a run without
+    op names or without an op under the scopes reads nothing."""
+    run = {"op_names": op_names, "steps": 2,
+           "trace": {"device_ops": OPS, "window": WINDOW}}
+    assert ph.scope_ns_per_step(run, (ph.PERTURB,)) == 10 / 2
+    assert ph.scope_ns_per_step(run, (ph.FORWARD,)) == 100 / 2
+    names = dict(op_names, **{"dot.5": "jit(step)/zo_forward/moe/dot"})
+    assert ph.scope_ns_per_step(dict(run, op_names=names), ("moe",)) \
+        == (30 + 30) / 2
+    assert ph.phase_of("jit(step)/zo_forward/moe/dot", ("moe",)) == "moe"
+    assert ph.phase_of("jit(step)/zo_forward/moe/dot") == ph.FORWARD
+    assert ph.scope_ns_per_step(dict(run, op_names={}), (ph.FORWARD,)) \
+        is None
+    assert ph.scope_ns_per_step({"job": "none"}, (ph.FORWARD,)) is None
+
+
 def test_an_op_with_no_phase_counts_in_none(op_names):
     phase = ph.op_phases(OPS, op_names)
     named = ph.phase_ns(OPS, phase, WINDOW, set(ph.PHASES))
@@ -108,3 +127,27 @@ def test_recorded_trace_by_phase(recorded):
     kernels = [p for o, p in zip(ops, phase)
                if any(n in o[0] for n in zo_train.KERNEL_NEEDLES)]
     assert kernels and set(kernels) <= {ph.PERTURB, ph.UPDATE}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("perturb_ms.train", 997757317 / 2 / 1e6),
+    ("perturb_roofline.train",
+     100 * 36_382_433_280 / 819e9 / (997757317 / 2 / 1e9)),
+    ("forward_ms.train", 270414988 / 2 / 1e6),
+    ("forward_mfu.train",
+     100 * 22_811_728_936_960 / (270414988 / 2 / 1e9) / 197e12)])
+def test_phase_readers_on_the_recorded_trace(recorded, name, want):
+    """The readers of ``BENCHMARK.json``'s phase metrics, on the recorded
+    two steps of zo-short (before the strip walk of the kernels) with the
+    run's context as ``kinds/zo_train.py`` hands it over."""
+    model = manifest.config("opt-13b-8l")["model"]
+    run = {"job": "zo_train", "steps": 2, "op_names": recorded["op_names"],
+           "trace": {"device_ops": recorded["device_ops"],
+                     "window": recorded["window"]},
+           "flops_per_step": 2 * flops.forward_flops(model, 16, 128),
+           "kernel_bytes_per_step": flops.zo_kernel_bytes_per_step(model),
+           "peaks": peaks.PEAKS["TPU v5 lite"]}
+    read = manifest.reader(name)
+    assert read(run) == pytest.approx(want, rel=1e-12)
+    assert read(dict(run, op_names={})) is None
+    assert read({"job": "none"}) is None

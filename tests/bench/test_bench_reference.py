@@ -1,9 +1,11 @@
-"""The benchmark's plain float32 reference against the program's forward, on
-the CPU at a small size: the teacher-forced forward, and prefill through the
-KV cache followed by decoding one token at a time.  Also the reference's
-copy of the z generator against the program's Pallas kernel (interpret
-mode): the same stream to within the last bits of float32 (the kernel
-pins each rounding stage; the copy lets the compiler fuse them)."""
+"""The benchmark's plain float32 reference against the program, on the CPU
+at a small size, for every model family under ``benchmarks/chip/reference``
+and each of its ``CPU_CASES``: the teacher-forced forward and loss through
+the registry's ``Bundle``, and prefill through the KV cache followed by
+decoding one token at a time.  Also the reference's copy of the z
+generator against the program's Pallas kernel (interpret mode): the same
+stream to within the last bits of float32 (the kernel pins each rounding
+stage; the copy lets the compiler fuse them)."""
 from __future__ import annotations
 
 import jax
@@ -11,19 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.chip import manifest
 from benchmarks.chip import weights as wgen
 from benchmarks.chip.reference import dense, zgen
 
-OPT_LIKE = {"name": "opt-tiny", "family": "dense", "n_layers": 2,
-            "d_model": 64, "n_heads": 4, "n_kv_heads": 4, "d_ff": 256,
-            "vocab_size": 300, "activation": "relu", "gated_ffn": False,
-            "norm": "layernorm", "qkv_bias": False, "rope_theta": 10000.0,
-            "max_seq": 64, "dtype": "float32"}
-QWEN_LIKE = {"name": "qwen-tiny", "family": "dense", "n_layers": 2,
-             "d_model": 64, "n_heads": 4, "n_kv_heads": 2, "d_ff": 128,
-             "vocab_size": 300, "activation": "silu", "gated_ffn": True,
-             "norm": "rmsnorm", "qkv_bias": True, "rope_theta": 1e6,
-             "max_seq": 64, "dtype": "float32"}
+CASES = [pytest.param(manifest.family(name), model, id=case)
+         for name in manifest.families()
+         for case, model in manifest.family(name).CPU_CASES.items()]
 TOL = 2e-4     # float32 on both sides; only the order of sums differs
 
 
@@ -50,23 +46,27 @@ def _tokens(model, seed, shape):
                                                 dtype=np.int32)
 
 
-@pytest.mark.parametrize("model", [OPT_LIKE, QWEN_LIKE],
-                         ids=["opt-like", "qwen2-like"])
-def test_reference_forward_matches_program(model):
-    from repro.models import transformer
+@pytest.mark.parametrize("family,model", CASES)
+def test_reference_forward_matches_program(family, model):
+    """The logits and the loss that the program's training step evaluates
+    against the family's reference, on the same weights."""
     b = _program(model)
     params = wgen.make(model, 7)
     toks = _tokens(model, 1, (2, 24))
-    prog = transformer.forward(b.cfg, params, tokens=jnp.asarray(toks)).logits
-    ref = dense.logits(model, *_plain_source(params), toks)
+    labels = _tokens(model, 3, (2, 24))
+    batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
+    prog = b.train_logits_fn()(params, batch)
+    ref = family.logits(model, *_plain_source(params), toks)
     V = model["vocab_size"]
     np.testing.assert_allclose(np.asarray(prog[..., :V]), np.asarray(ref),
                                atol=TOL, rtol=TOL)
+    loss = family.loss(model, *_plain_source(params), toks, labels)
+    np.testing.assert_allclose(float(b.loss_fn()(params, batch)), loss,
+                               atol=TOL, rtol=TOL)
 
 
-@pytest.mark.parametrize("model", [OPT_LIKE, QWEN_LIKE],
-                         ids=["opt-like", "qwen2-like"])
-def test_reference_matches_prefill_then_decode(model):
+@pytest.mark.parametrize("family,model", CASES)
+def test_reference_matches_prefill_then_decode(family, model):
     b = _program(model)
     params = wgen.make(model, 8)
     P, T = 10, 6
@@ -78,7 +78,7 @@ def test_reference_matches_prefill_then_decode(model):
         lg, cache = decode(params, {"token": jnp.asarray(toks[:, t:t + 1]),
                                     "cache": cache, "cache_pos": jnp.int32(t)})
         got.append(np.asarray(lg[:, 0]))
-    ref = np.asarray(dense.logits(model, *_plain_source(params), toks))
+    ref = np.asarray(family.logits(model, *_plain_source(params), toks))
     V = model["vocab_size"]
     for i, g in enumerate(got):
         np.testing.assert_allclose(g[:, :V], ref[:, P - 1 + i], atol=TOL,
@@ -108,7 +108,7 @@ def test_reference_z_is_the_kernels_z():
 
 
 def test_weights_one_leaf_is_the_same_as_all():
-    model = dict(QWEN_LIKE, dtype="bfloat16")
+    model = dict(dense.CPU_CASES["qwen2-like"], dtype="bfloat16")
     params = wgen.make(model, 99)
     flat = {"/".join(k.key for k in path): leaf for path, leaf in
             jax.tree_util.tree_flatten_with_path(params)[0]}
